@@ -151,7 +151,7 @@ def _time_reps(run, carry0, xs, reps: int = REPS, warmup: int = WARMUP):
     """Returns (final_out, stats): warm-up through compilation, then
     ``reps`` timed repetitions (block_until_ready) under the x64 metric
     context ``simulate`` uses."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         out = None
         for _ in range(warmup):
             out = jax.block_until_ready(run(carry0, xs))

@@ -39,7 +39,6 @@ import tempfile
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.checkpointer import Checkpointer
@@ -105,27 +104,13 @@ class _KilledAfterSave(Checkpointer):
         return out
 
 
-def _lean_op(nodes: int, slots: int):
-    """Closure-free versioned bump: each round every node inflates one
-    (t, node)-derived slot of every object. Shape-agnostic (the object
-    extent comes from x), so the same op drives sharded stores too."""
-
-    def op(x, t):
-        rows = jnp.arange(nodes)
-        slot = (t * 5 + rows) % slots
-        cur = x[:, rows, slot]
-        return jnp.zeros_like(x).at[:, rows, slot].set(cur + 1)
-
-    return op
-
-
 def scale_curve(smoke=False, verbose=True):
     """Chunked + metrics-reduced store, 4K → 1M objects: per-object peak
     live-buffer bytes must stay flat (DESIGN.md §16)."""
     scales = SCALE_SMOKE_SCALES if smoke else SCALE_SCALES
     topo = C.topo_of("ring", S_NODES)
     lat = MapLattice(S_SLOTS, vl.max_int(), "scale").build()
-    op = _lean_op(S_NODES, S_SLOTS)
+    op = W.rotating_slot_op(S_NODES, S_SLOTS)
 
     rows = []
     for objects in scales:
@@ -165,7 +150,8 @@ def chunk_resume_exercise(verbose=True):
     objects = 512
     topo = C.topo_of("ring", S_NODES)
     lat = MapLattice(S_SLOTS, vl.max_int(), "scale").build()
-    spec = StoreSpec(objects=objects, op_fn=_lean_op(S_NODES, S_SLOTS))
+    spec = StoreSpec(objects=objects,
+                     op_fn=W.rotating_slot_op(S_NODES, S_SLOTS))
 
     full = simulate_store(ALGO, lat, topo, spec, active_rounds=S_ROUNDS,
                           chunk_rounds=S_CHUNK)

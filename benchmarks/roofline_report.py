@@ -68,7 +68,7 @@ def kernel_report(full: bool = False, verbose: bool = True):
             for eng in ENGINES:
                 alg, carry0, step, t0 = _round_fn(algo, lat, topo, op_fn,
                                                   eng)
-                with jax.experimental.enable_x64():
+                with jax.enable_x64(True):
                     jitted = jax.jit(step)
                     compiled = jitted.lower(carry0, t0).compile()
                     out = jax.block_until_ready(jitted(carry0, t0))

@@ -180,6 +180,9 @@ def main() -> None:
     ap.add_argument("--list-sections", action="store_true",
                     help="print the section registry and exit")
     args = ap.parse_args()
+    from repro.launch.device import enable_compile_cache
+
+    enable_compile_cache(SUMMARY.parent)
     if args.list_sections:
         for name, (title, _) in REGISTRY.items():
             print(f"  {name:10s} {title}")

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import grid_for, interpret_default
+from repro.kernels.common import grid_for, interpret_default, pallas_call
 
 FOLD_BLOCK = (256, 256)
 
@@ -79,7 +79,7 @@ def buffer_fold_2d(buf, *, kind: str = "max", block=FOLD_BLOCK,
         in_spec = pl.BlockSpec((k, bm, bn), lambda i, j: (0, i, j))
         out_spec = pl.BlockSpec((k - 1, bm, bn), lambda i, j: (0, i, j))
         out_shape = jax.ShapeDtypeStruct((k - 1, m, n), buf.dtype)
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_fold_kernel, k=k, kind=kind, batched=batched),
         grid=grid,
         in_specs=[in_spec],

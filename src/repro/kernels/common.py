@@ -16,12 +16,18 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 # Default VMEM tile: 512×1024 int32 = 2 MiB per operand — comfortably inside
 # the ~16 MiB/core VMEM budget with 2-3 operands + outputs double-buffered.
 DEFAULT_BLOCK = (512, 1024)
 LANE = 128
 SUBLANE = 8
+
+# Kernel view of boolean states: {0, 1} words under max (≡ or). TPU
+# tiles have no bool layout, and v5e's Mosaic refuses 8-bit compares, so
+# the view is 32-bit.
+BOOL_VIEW = jnp.int32
 
 _TRUE = ("1", "true", "yes", "on")
 _FALSE = ("0", "false", "no", "off")
@@ -41,6 +47,25 @@ def interpret_default() -> bool:
     if env in _FALSE:
         return False
     return jax.default_backend() != "tpu"
+
+
+def pallas_call(kernel, **kw):
+    """``pl.pallas_call`` traced with 32-bit defaults.
+
+    The simulator traces its scan under ``jax.enable_x64`` so the metric
+    accumulators are int64 (DESIGN.md §10). Traced there, a kernel's grid
+    indices and index maps become 64-bit, which Mosaic cannot lower
+    (``failed to legalize operation 'func.return'``). Every kernel operand
+    and output carries an explicit 32-bit-or-narrower dtype, so tracing the
+    call with x64 off changes no value — only the index arithmetic's width.
+    """
+    call = pl.pallas_call(kernel, **kw)
+
+    def run(*args):
+        with jax.enable_x64(False):
+            return call(*args)
+
+    return run
 
 
 def backend_key() -> str:

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from repro.kernels import common, ref
 from repro.kernels.buffer_fold import FOLD_BLOCK, buffer_fold_2d
 from repro.kernels.common import (
+    BOOL_VIEW,
     DEFAULT_BLOCK,
     interpret_default,
     pad_to_2d,
@@ -22,7 +23,7 @@ from repro.kernels.common import (
 )
 from repro.kernels.common import LANE, SUBLANE
 from repro.kernels.delta_extract import delta_extract_2d
-from repro.kernels.digest import DIGEST_BLOCK, digest_blocks_2d, masked_extract_2d
+from repro.kernels.digest import digest_blocks_2d, digest_tile, masked_extract_2d
 from repro.kernels.join import join_2d
 from repro.kernels.lex_join import lex_join_delta_2d
 from repro.kernels.round_recv import ROUND_BLOCK, round_recv_2d
@@ -145,8 +146,8 @@ def round_recv(d_stack, x, *, kind: str = "max", block=None, interpret=None,
     large tiles instead of C tiny grid steps. Every per-row computation
     is independent, so both layouts are bit-identical.
 
-    Boolean states are viewed as uint8 {0, 1} for the kernel (max ≡ or, and
-    TPU tiles have no bool layout) and cast back — bit-identical.
+    Boolean states are viewed as ``common.BOOL_VIEW`` {0, 1} words for the
+    kernel (max ≡ or) and cast back — bit-identical.
     """
     interpret = interpret_default() if interpret is None else interpret
     if x.ndim == 3 and layout == "rows":
@@ -177,8 +178,8 @@ def round_recv(d_stack, x, *, kind: str = "max", block=None, interpret=None,
         assert x.shape == (b, u)
     orig_dtype = x.dtype
     if orig_dtype == jnp.bool_:
-        d_stack = d_stack.astype(jnp.uint8)
-        x = x.astype(jnp.uint8)
+        d_stack = d_stack.astype(BOOL_VIEW)
+        x = x.astype(BOOL_VIEW)
     if block is None:
         # Short universes take one lane-aligned tile instead of the full
         # default width so interpret-mode tests don't pad 10×.
@@ -221,10 +222,10 @@ def round_recv(d_stack, x, *, kind: str = "max", block=None, interpret=None,
 
 # -- single-launch sync round (megakernel, DESIGN.md §17) ---------------------
 
-def _routes_for(nbrs, rev, np_: int):
+def _routes_for(nbrs, rev):
     """Static routing table for the megakernel: routes[q][n] =
     (sender_slot, sender_node) realizing inbox[n, q] = d_all[nbrs[n, q],
-    rev[n, q]]. Node-axis padding rows route to (0, 0) — inert under the
+    rev[n, q]]. Topology padding slots route to (0, 0) — inert under the
     kernel's active mask."""
     import numpy as np
 
@@ -232,9 +233,16 @@ def _routes_for(nbrs, rev, np_: int):
     rev = np.asarray(rev)
     n, p = nbrs.shape
     return tuple(
-        tuple((int(rev[i, q]), int(nbrs[i, q])) if i < n else (0, 0)
-              for i in range(np_))
+        tuple((int(rev[i, q]), int(nbrs[i, q])) for i in range(n))
         for q in range(p))
+
+
+def _lane_tiles(u: int):
+    """Universe tile widths for the megakernel: lane multiples below u,
+    and u itself (a block spanning the whole axis needs no lane padding —
+    the store's short objects keep their unpadded width in HBM)."""
+    return sorted({w for w in (128, 256, 512, 1024, 2048) if w < u}
+                  | ({u} if u <= 2048 else set()))
 
 
 def sync_round_block(b: int, n: int, u: int, *, p: int, k: int,
@@ -244,23 +252,23 @@ def sync_round_block(b: int, n: int, u: int, *, p: int, k: int,
     autotuned (kernels.common.tuned_block) with a heuristic default.
 
     ``b``: configs, ``n``: nodes, ``u``: flattened universe, ``p``: degree,
-    ``k``: buffer slots (0 = state-based). Returns ``((g, bn), source)``.
+    ``k``: buffer slots (0 = state-based). Every tile holds the whole node
+    axis. Returns ``((g, bn), source)``.
     """
     interpret = interpret_default() if interpret is None else interpret
-    np_ = -(-n // SUBLANE) * SUBLANE
-    full_u = -(-u // LANE) * LANE
-    bn_opts = sorted({min(v, full_u) for v in (128, 256, 512, 1024, 2048)})
+    np_ = -(-n // SUBLANE) * SUBLANE             # VMEM rows per config
+    bn_opts = _lane_tiles(u)
     if layout == "rows" and b > 1:
         g_opts = sorted({min(b, g) for g in (1, max(1, 64 // np_),
                                              max(1, 256 // np_))})
         g_default = min(b, max(1, 64 // np_))
     else:
         g_opts, g_default = [1], 1
-    default = (g_default, min(1024, full_u))
+    default = (g_default, u if u <= 1024 else 1024)
     cands = [default] + [(g, bn) for g in g_opts for bn in bn_opts
                          if (g, bn) != default]
     key = (common.backend_key(), kind, f"p{p}", f"k{k}", layout, f"n{np_}",
-           f"b{common.shape_bucket(b)}", f"u{common.shape_bucket(full_u)}")
+           f"b{common.shape_bucket(b)}", f"u{common.shape_bucket(u)}")
     return common.tuned_block("round_step", key, cands, tune_bench)
 
 
@@ -301,28 +309,25 @@ def sync_round(delta, x, buf, active, delivered, *, nbrs, rev,
                                     layout=layout, interpret=interpret)
     g, bn = block
     g = max(1, min(g, b))
-    np_ = -(-n // SUBLANE) * SUBLANE
+    bn = min(bn, u)
     b_pad = -(-b // g) * g
     u_pad = -(-u // bn) * bn
-    routes = _routes_for(nbrs, rev, np_)
+    routes = _routes_for(nbrs, rev)
 
     orig_dtype = x.dtype
-    cast = jnp.uint8 if orig_dtype == jnp.bool_ else orig_dtype
+    cast = BOOL_VIEW if orig_dtype == jnp.bool_ else orig_dtype
 
-    def pad3(a):
+    def pad(a, lead=()):
         return jnp.pad(a.astype(cast),
-                       ((0, b_pad - b), (0, np_ - n), (0, u_pad - u)))
+                       lead + ((0, b_pad - b), (0, 0), (0, u_pad - u)))
 
-    d2, x2 = pad3(delta), pad3(x)
+    d2, x2 = pad(delta), pad(x)
     if has_buffer:
-        b2 = jnp.pad(buf.astype(cast),
-                     ((0, 0), (0, b_pad - b), (0, np_ - n), (0, u_pad - u)))
-        dlv = jnp.pad(delivered.astype(jnp.int32),
-                      ((0, b_pad - b), (0, np_ - n)))
+        b2 = pad(buf, ((0, 0),))
+        dlv = jnp.pad(delivered.astype(jnp.int32), ((0, b_pad - b), (0, 0)))
     else:
         b2, dlv = None, None
-    a2 = jnp.pad(active.astype(jnp.int32),
-                 ((0, b_pad - b), (0, np_ - n), (0, 0)))
+    a2 = jnp.pad(active.astype(jnp.int32), ((0, b_pad - b), (0, 0), (0, 0)))
 
     xo, bo, ib, nodecnt, ssend, cnt, dsz = round_step_2d(
         d2, x2, b2, a2, dlv, routes=routes, kind=kind,
@@ -337,9 +342,9 @@ def sync_round(delta, x, buf, active, delivered, *, nbrs, rev,
         ib = ib[:, :b, :n, :u].astype(orig_dtype)
 
     def trim(c):
-        # [GB, GJ, g, Np, C] -> sum universe tiles -> [B, N, C]
+        # [GB, GJ, g, N, C] -> sum universe tiles -> [B, N, C]
         t = c.sum(axis=1, dtype=jnp.int32)
-        return t.reshape((b_pad, np_) + t.shape[3:])[:b, :n]
+        return t.reshape((b_pad, n) + t.shape[3:])[:b]
 
     nodecnt = trim(nodecnt)
     return (xo, bo, ib, nodecnt[..., 0], nodecnt[..., 1],
@@ -347,16 +352,6 @@ def sync_round(delta, x, buf, active, delivered, *, nbrs, rev,
 
 
 # -- digest subsystem (DESIGN.md §14) ----------------------------------------
-
-def _digest_tile(u: int, be: int):
-    """Digest tile: lane-aligned, block-aligned (be is a power of two, so
-    any 128-multiple width is block-aligned for be <= 128; wider blocks
-    round the tile up to a block multiple)."""
-    bn = min(512, -(-u // LANE) * LANE)
-    bn = max(bn, be)
-    bn = -(-bn // be) * be
-    return (DIGEST_BLOCK[0], bn)
-
 
 def digest_blocks(x, *, block_elems: int, kind: str = "max", interpret=None,
                   batched: bool = False, layout: str = "grid"):
@@ -378,10 +373,10 @@ def digest_blocks(x, *, block_elems: int, kind: str = "max", interpret=None,
         return out.reshape((b, n) + out.shape[1:])
     m, u = x.shape[-2], x.shape[-1]
     nb = -(-u // block_elems)
-    block = _digest_tile(u, block_elems)
-    bm, bn = block
+    block = digest_tile(u, block_elems)
+    bm, nb_t = block
     m_pad = -(-m // bm) * bm
-    n_pad = -(-u // bn) * bn
+    n_pad = -(-nb // nb_t) * nb_t * block_elems
     lead = ((0, 0),) if batched else ()
     v = jnp.pad(x.astype(jnp.uint32),
                 lead + ((0, m_pad - m), (0, n_pad - u)))
@@ -411,14 +406,14 @@ def masked_extract(x, block_masks, *, block_elems: int, interpret=None,
     p = block_masks.shape[-2]
     nb = -(-u // block_elems)
     assert block_masks.shape[-1] == nb
-    block = _digest_tile(u, block_elems)
-    bm, bn = block
+    block = digest_tile(u, block_elems)
+    bm, nb_t = block
     m_pad = -(-m // bm) * bm
-    n_pad = -(-u // bn) * bn
-    nb_pad = n_pad // block_elems
+    nb_pad = -(-nb // nb_t) * nb_t
+    n_pad = nb_pad * block_elems
     orig_dtype = x.dtype
     if orig_dtype == jnp.bool_:
-        x = x.astype(jnp.uint8)
+        x = x.astype(BOOL_VIEW)
     lead = ((0, 0),) if batched else ()
     x2 = jnp.pad(x, lead + ((0, m_pad - m), (0, n_pad - u)))
     # [(B,) N, P, nB] -> [P, (B,) N_pad, nB_pad] int32

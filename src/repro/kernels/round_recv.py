@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import grid_for, interpret_default
+from repro.kernels.common import grid_for, interpret_default, pallas_call
 
 # Node-axis sublanes × universe-axis lanes. The node axis of real
 # deployments is small next to the universe axis, so the default tile is
@@ -177,7 +177,7 @@ def round_recv_2d(d, x, active=None, *, kind: str = "max", block=ROUND_BLOCK,
         + ([jax.ShapeDtypeStruct(d.shape, d.dtype)] if emit_stored else []) \
         + ([jax.ShapeDtypeStruct(x.shape, jnp.int32)] if emit_cov else []) \
         + [cnt_shape, cnt_shape]
-    outs = pl.pallas_call(
+    outs = pallas_call(
         functools.partial(_round_recv_kernel, p=p, kind=kind,
                           emit_stored=emit_stored, emit_cov=emit_cov,
                           batched=batched),

@@ -14,12 +14,13 @@ K updated slots, and the per-(node, slot) counts the metric epilogue needs.
 The trick that makes in-kernel *routing* possible: the topology's
 ``nbrs``/``rev`` tables are trace-time constants ([N, P] numpy, N small),
 so ``inbox[n, q] = send[nbrs[n,q]][rev[n,q]]`` unrolls into N·P static row
-selects over the send values already in VMEM — the whole (padded) node
-axis rides inside every tile, and the gather that previously streamed the
-[N, P, U] send block through HBM disappears.
+selects over the send values already in VMEM — the whole node axis rides
+inside every tile, and the gather that previously streamed the [N, P, U]
+send block through HBM disappears.
 
-Tile layout [g, Np, bn]: Np = node axis padded to sublanes (whole axis per
-tile, required for routing); bn = universe lanes; g = configs per tile.
+Tile layout [g, N, bn]: N = the whole node axis (required for routing; a
+block spanning a whole axis needs no sublane padding); bn = universe lanes,
+a multiple of 128 or the whole universe; g = configs per tile.
 g=1 serves unbatched runs and the sweep engine's "grid" layout (one config
 per batch-grid step); g>1 folds the store engine's many small objects into
 tall tiles ("rows" layout) — per-config programs are identical either way,
@@ -45,7 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import interpret_default
+from repro.kernels.common import interpret_default, pallas_call
 
 
 def _count_rows(v, kind: str):
@@ -64,7 +65,6 @@ def _round_step_kernel(d_ref, x_ref, *refs, g: int, np_: int, p: int, k: int,
     refs = list(refs)
     buf_ref = refs.pop(0) if has_buffer else None
     act_ref = refs.pop(0)
-    dlv_ref = refs.pop(0) if has_buffer else None
     xo_ref = refs.pop(0)
     bo_ref = refs.pop(0) if has_buffer else None
     ib_ref = refs.pop(0) if emit_inbox else None
@@ -74,7 +74,7 @@ def _round_step_kernel(d_ref, x_ref, *refs, g: int, np_: int, p: int, k: int,
     zero = jnp.zeros((), x_ref.dtype)
 
     # (1) local update: δ joins into x and the self slot  [Alg 2, lines 6-8]
-    x = x_ref[...]                                         # [g, Np, bn]
+    x = x_ref[...]                                         # [g, N, bn]
     d0 = d_ref[...]
     nc_ref[0, 0, :, :, 0] = _count_rows(d0, kind)          # |⇓δ| per node
     x = op(x, d0)
@@ -103,20 +103,26 @@ def _round_step_kernel(d_ref, x_ref, *refs, g: int, np_: int, p: int, k: int,
     for j in range(p):
         ss_ref[0, 0, :, :, j] = _count_rows(sends[j], kind)
 
+    # Masks are int32 columns compared after the lane broadcast: Mosaic
+    # cannot reshape an i1 vector to add the lane axis.
+    act = act_ref[...]                          # [g, N, P (+1: delivered)]
+
+    def column(c):
+        return jnp.broadcast_to(act[:, :, c:c + 1], x.shape)
+
     # (3) ack-gated buffer clear                          [Alg 2, line 13]
     if has_buffer:
-        retain = (dlv_ref[...] == 0)[:, :, None]           # [g, Np, 1]
+        retain = column(p) == 0
         slots = [jnp.where(retain, s, zero) for s in slots]
 
     # (4) route + receive all P slots in order            [Alg 2, lines 14-17]
-    act = act_ref[...]                                     # [g, Np, P]
     for q in range(p):
         # Static routing: inbox[n] = sends[rev[n,q]] of node nbrs[n,q].
-        # Padding rows route to (0, 0) and are masked off below.
+        # Topology padding slots route to (0, 0), masked off below.
         dq = jnp.stack(
             [sends[routes[q][n][0]][:, routes[q][n][1], :]
-             for n in range(np_)], axis=1)                 # [g, Np, bn]
-        d = jnp.where(act[:, :, q][:, :, None] != 0, dq, zero)
+             for n in range(np_)], axis=1)                 # [g, N, bn]
+        d = jnp.where(column(q) != 0, dq, zero)
         if kind == "max":
             novel = d > x
             s = jnp.where(novel, d, zero)
@@ -151,12 +157,15 @@ def round_step_2d(delta, x, buf, active, delivered, *, routes,
                   block=(1, 512), interpret: bool | None = None):
     """One full sync round over tile-aligned canonical operands.
 
-    ``delta``/``x``: [B, Np, U] (B a multiple of g, Np the whole padded
-    node axis, U a multiple of bn); ``buf``: [K, B, Np, U] or None;
-    ``active``: int32 [B, Np, P]; ``delivered``: int32 [B, Np] or None
-    (required iff buf is given). ``routes``: static tuple-of-tuples,
-    routes[q][n] = (sender_slot, sender_node) realizing
-    inbox[n, q] = d_all[nbrs[n,q], rev[n,q]]. ``block`` = (g, bn).
+    ``delta``/``x``: [B, N, U] (B a multiple of g, N the whole node axis,
+    U a multiple of bn); ``buf``: [K, B, N, U] or None; ``active``: int32
+    [B, N, P]; ``delivered``: int32 [B, N] or None (required iff buf is
+    given). The kernel reads ``delivered`` as a last column of ``active``:
+    a [g, N] block of its own would break the (8, 128) block rule whenever
+    g < B. ``x`` and ``buf`` are updated in place (aliased to x', buf').
+    ``routes``: static tuple-of-tuples, routes[q][n] = (sender_slot,
+    sender_node) realizing inbox[n, q] = d_all[nbrs[n,q], rev[n,q]].
+    ``block`` = (g, bn).
 
     ``extracts`` merges the slot-order Δ extractions into the buffer
     in-kernel (rr/bprr). Historically it was the complement of
@@ -166,9 +175,9 @@ def round_step_2d(delta, x, buf, active, delivered, *, routes,
     ``has_buffer and not emit_inbox``.
 
     Returns ``(x', buf', inbox, nodecnt, ssend, cnt, dsz)``:
-    buf' [K, B, Np, U] (None without buffer), inbox [P, B, Np, U] (None
-    unless ``emit_inbox``), nodecnt [GB, GJ, g, Np, 2] int32 with channels
-    (|⇓δ|, |⇓x'|), and ssend/cnt/dsz [GB, GJ, g, Np, P] per-block counts —
+    buf' [K, B, N, U] (None without buffer), inbox [P, B, N, U] (None
+    unless ``emit_inbox``), nodecnt [GB, GJ, g, N, 2] int32 with channels
+    (|⇓δ|, |⇓x'|), and ssend/cnt/dsz [GB, GJ, g, N, P] per-block counts —
     sum the GJ axis for totals.
     """
     interpret = interpret_default() if interpret is None else interpret
@@ -186,7 +195,6 @@ def round_step_2d(delta, x, buf, active, delivered, *, routes,
     assert not (extracts and not has_buffer)
 
     d_spec = pl.BlockSpec((g, np_, bn), lambda i, j: (i, 0, j))
-    a_spec = pl.BlockSpec((g, np_, p), lambda i, j: (i, 0, 0))
     nc_spec = pl.BlockSpec((1, 1, g, np_, 2), lambda i, j: (i, j, 0, 0, 0))
     sl_spec = pl.BlockSpec((1, 1, g, np_, p), lambda i, j: (i, j, 0, 0, 0))
     nc_shape = jax.ShapeDtypeStruct((gb, gj, g, np_, 2), jnp.int32)
@@ -198,11 +206,13 @@ def round_step_2d(delta, x, buf, active, delivered, *, routes,
         b_spec = pl.BlockSpec((k, g, np_, bn), lambda i, j: (0, i, 0, j))
         in_specs.append(b_spec)
         args.append(buf)
-    in_specs.append(a_spec)
-    args.append(active.astype(jnp.int32))
+    act = active.astype(jnp.int32)
     if has_buffer:
-        in_specs.append(pl.BlockSpec((g, np_), lambda i, j: (i, 0)))
-        args.append(delivered.astype(jnp.int32))
+        act = jnp.concatenate(
+            [act, delivered.astype(jnp.int32)[:, :, None]], axis=-1)
+    in_specs.append(pl.BlockSpec((g, np_, act.shape[-1]),
+                                 lambda i, j: (i, 0, 0)))
+    args.append(act)
 
     out_specs = [d_spec]
     out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype)]
@@ -216,7 +226,11 @@ def round_step_2d(delta, x, buf, active, delivered, *, routes,
     out_specs += [nc_spec, sl_spec, sl_spec, sl_spec]
     out_shape += [nc_shape, sl_shape, sl_shape, sl_shape]
 
-    outs = pl.pallas_call(
+    # x' and buf' overwrite x and buf in place: each grid step reads its
+    # blocks before writing the same blocks back, and a store's buffer
+    # stack is too large to hold twice in HBM.
+    aliases = {1: 0, 2: 1} if has_buffer else {1: 0}
+    outs = pallas_call(
         functools.partial(_round_step_kernel, g=g, np_=np_, p=p, k=k,
                           kind=kind, per_origin=per_origin,
                           emit_inbox=emit_inbox, extracts=extracts,
@@ -225,6 +239,7 @@ def round_step_2d(delta, x, buf, active, delivered, *, routes,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        input_output_aliases=aliases,
         interpret=interpret,
     )(*args)
 

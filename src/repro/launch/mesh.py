@@ -11,12 +11,7 @@ import jax
 
 
 def _axis_type_kwargs(n: int) -> dict:
-    """``axis_types`` exists from jax 0.5; older versions (0.4.x) only have
-    implicitly-Auto axes, which is the behavior we request anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -43,16 +38,6 @@ def sweep_mesh(num_devices: int | None = None):
     of cells with no cross-device collectives."""
     n = len(jax.devices()) if num_devices is None else num_devices
     return jax.make_mesh((n,), (SWEEP_AXIS,), **_axis_type_kwargs(1))
-
-
-def _shard_map():
-    """`shard_map` moved out of jax.experimental in newer jax; resolve the
-    available entry point lazily so importing this module stays cheap."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    from jax.experimental.shard_map import shard_map as fn
-    return fn
 
 
 def axis_shards(mesh, axis: str) -> int:
@@ -111,9 +96,9 @@ def _shard_axis_scan(run, batch: int, mesh, axis: str, what: str,
         out_carry, out_ys = jax.eval_shape(run, carry0, xs)
         out_specs = (jax.tree.map(lambda _: cfg0, out_carry),
                      jax.tree.map(lambda _: cfg1, out_ys))
-        return _shard_map()(
+        return jax.shard_map(
             run, mesh=mesh, in_specs=(carry_spec, xs_spec),
-            out_specs=out_specs, check_rep=False)(carry0, xs)
+            out_specs=out_specs, check_vma=False)(carry0, xs)
 
     return wrapped
 

@@ -1,7 +1,7 @@
 """Three-term roofline model from the compiled dry-run artifact.
 
-Target hardware: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI
-(constants from the assignment).
+Target hardware: TPU v5e — 197 TFLOP/s bf16 and 819 GB/s HBM from the
+device table in ``launch/device.py``, ~50 GB/s/link ICI.
 
     compute term    = HLO_FLOPs_per_device / peak_FLOPs
     memory term     = HLO_bytes_per_device / HBM_bw
@@ -16,8 +16,11 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12        # bf16 / chip
-HBM_BW = 819e9             # bytes/s / chip
+from repro.launch.device import peaks
+
+TARGET = "TPU v5 lite"     # device_kind this model prices HLO for
+PEAK_FLOPS = peaks(TARGET)["bf16_flops"]         # / chip
+HBM_BW = peaks(TARGET)["hbm_bytes_per_s"]        # bytes/s / chip
 LINK_BW = 50e9             # bytes/s / link
 
 

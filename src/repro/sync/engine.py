@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels import common as kcommon
 from repro.kernels import ops as kops
 
 ENGINES = ("reference", "fused", "mega")
@@ -348,7 +349,7 @@ def fused_loo_sends(buf, kind: str, batched: bool = False,
     store engine's ``rows`` layout). Returns [(B,) N, P, U]."""
     orig_dtype = buf.dtype
     if orig_dtype == jnp.bool_:
-        buf = buf.astype(jnp.uint8)                      # max ≡ or on {0, 1}
+        buf = buf.astype(kcommon.BOOL_VIEW)              # max ≡ or on {0, 1}
     sax = 2 if batched else 1
     stack = jnp.moveaxis(buf, sax, 0)                    # [P+1, (B,) N, U]
     sends = kops.buffer_fold(stack, kind=kind, batched=batched,

@@ -23,7 +23,7 @@ makes the sweep invariant checkable: cell b of a sweep runs the *same*
 step program as a single ``simulate`` call, just with batched carries.
 
 Metrics are accumulated in int64 (DESIGN.md §10): the scan is traced under
-``jax.experimental.enable_x64`` so fleet-scale universe × degree × rounds
+``jax.enable_x64`` so fleet-scale universe × degree × rounds
 sums cannot wrap the int32 range. Lattice state dtypes are unaffected (all
 states carry explicit dtypes). Set ``wide_metrics=False`` to opt out.
 """
@@ -244,7 +244,7 @@ def run_scan(step, carry0, xs, jit: bool, wide_metrics: bool,
     if jit:
         run = jax.jit(run)
     if wide_metrics:
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             return run(carry0, xs)
     return run(carry0, xs)
 
@@ -305,7 +305,7 @@ def run_scan_chunked(step, carry0, xs, jit: bool, wide_metrics: bool,
                          chunks[0])
 
     if wide_metrics:
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             drive()
     else:
         drive()

@@ -742,7 +742,7 @@ def _simulate_store(algo, lattice, topo, spec, active_rounds, quiet_rounds,
         if wide_metrics:
             # int64 metric prefixes would silently downcast to int32
             # outside the x64 context (jnp.asarray in restore).
-            with jax.experimental.enable_x64():
+            with jax.enable_x64(True):
                 bundle = ckpt_r.restore(at, like)
         else:
             bundle = ckpt_r.restore(at, like)

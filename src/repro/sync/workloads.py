@@ -216,6 +216,22 @@ def versioned_slot_cell_op(counts: np.ndarray, obj: int,
     return op_fn
 
 
+def rotating_slot_op(nodes: int, slots: int) -> Callable:
+    """Table-free store op stream over versioned-slot objects: each round
+    every node bumps one (round, node)-derived slot of every object. The
+    object extent comes from ``x``, so the same op drives a store whose
+    object axis is sharded across devices (``simulate_store(shard=True)``),
+    which :func:`versioned_slot_op` cannot."""
+
+    def op_fn(x, t):
+        rows = jnp.arange(nodes)
+        slot = (t * 5 + rows) % slots
+        cur = x[:, rows, slot]
+        return jnp.zeros_like(x).at[:, rows, slot].set(cur + 1)
+
+    return op_fn
+
+
 # ---------------------------------------------------------------------------
 # Table I micro-benchmark streams (Fig 7–10 harnesses, benchmarks/common.py)
 # ---------------------------------------------------------------------------

@@ -42,7 +42,7 @@ def test_roundtrip_mixed_dtypes(tmp_path):
     assert ck.available_steps() == [3]
     mf = ck.manifest(3)
     assert mf["digest"] == digest and mf["extra"] == {"note": "t"}
-    with jax.experimental.enable_x64():          # keep int64 leaves wide
+    with jax.enable_x64(True):          # keep int64 leaves wide
         out = ck.restore(3, _like(state))
     for got, want in zip(jax.tree.leaves(out), jax.tree.leaves(state)):
         assert got.dtype == np.asarray(want).dtype
