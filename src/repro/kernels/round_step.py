@@ -241,6 +241,7 @@ def round_step_2d(delta, x, buf, active, delivered, *, routes,
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
+        name="round_step",
     )(*args)
 
     outs = list(outs)

@@ -11,17 +11,21 @@ two ways:
   https://ui.perfetto.dev;
 * ``export_jsonl(path)`` — one JSON object per line, the greppable log.
 
-``annotate(name)`` wraps ``jax.profiler.TraceAnnotation`` (no-op when the
-profiler is unavailable) so the bench harness can label kernel launches
-for device-side profiles without a hard dependency.
+Every ``span`` also opens a ``jax.profiler.TraceAnnotation`` of its name,
+so under ``jax.profiler`` the same spans label the profiler's host
+timeline, on the clock of the device events. ``annotate(name)`` is that
+annotation alone, for regions no log records.
 """
 
 from __future__ import annotations
 
 import contextlib
+import itertools
 import json
 import time
 from typing import Optional
+
+import jax
 
 _PID = 1          # single-process traces; tid separates tracks
 TID_PHASES = 1    # host phase spans (build / compile / scan / export)
@@ -36,6 +40,8 @@ class TraceLog:
         self._clock = clock
         self._t0 = clock()
         self.events: list[dict] = []
+        self._open: list[tuple] = []        # (name, call) of open spans
+        self._calls = itertools.count()
 
     def _now_us(self) -> float:
         return (self._clock() - self._t0) * 1e6
@@ -62,11 +68,24 @@ class TraceLog:
 
     @contextlib.contextmanager
     def span(self, name: str, **args):
-        """Measure a host phase as a complete event (wall clock)."""
+        """Measure a host phase as a complete event (wall clock) inside a
+        ``jax.profiler.TraceAnnotation`` of the same name.
+
+        The event's args hold ``call``, the id of the outermost open span
+        (a span opened with none open starts a new call), ``parent``, the
+        name of the enclosing span (None at the root), and ``args``. The
+        body gets that dict and may add the counts taken at the span's
+        end."""
+        parent, call = self._open[-1] if self._open \
+            else (None, next(self._calls))
+        args = {"call": call, "parent": parent, **args}
+        self._open.append((name, call))
         t0 = self._now_us()
         try:
-            yield self
+            with jax.profiler.TraceAnnotation(name, call=call):
+                yield args
         finally:
+            self._open.pop()
             self.complete(name, t0, self._now_us() - t0, **args)
 
     # -- telemetry counter tracks --------------------------------------------
@@ -163,12 +182,15 @@ class TraceLog:
 
 
 def annotate(name: str):
-    """Label a region for device-side profiling: resolves to
-    ``jax.profiler.TraceAnnotation`` when available, else a no-op context
-    (keeps the bench harness runnable on stripped-down jax builds)."""
-    try:
-        import jax
+    """Label a region of the profiler's host timeline
+    (``jax.profiler.TraceAnnotation``)."""
+    return jax.profiler.TraceAnnotation(name)
 
-        return jax.profiler.TraceAnnotation(name)
-    except (ImportError, AttributeError):
-        return contextlib.nullcontext()
+
+def maybe_span(trace: Optional[TraceLog], name: str, **args):
+    """``trace.span(name, **args)``, or with ``trace=None`` a context that
+    records nothing and yields a dict the body may fill and nobody
+    reads."""
+    if trace is None:
+        return contextlib.nullcontext({})
+    return trace.span(name, **args)

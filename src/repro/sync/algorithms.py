@@ -286,13 +286,14 @@ class SyncAlgorithm:
                                       acc, faults=faults,
                                       want_recv=recv_counts,
                                       want_inbox=want_inbox)
-            node_mem = state_elems.astype(acc) + buf_elems.astype(acc)
-            metrics = RoundMetrics(
-                tx=tx,
-                mem=jnp.sum(node_mem, axis=-1),
-                cpu=cpu,
-                max_mem_node=jnp.max(node_mem, axis=-1),
-            )
+            with jax.named_scope("round_metrics"):
+                node_mem = state_elems.astype(acc) + buf_elems.astype(acc)
+                metrics = RoundMetrics(
+                    tx=tx,
+                    mem=jnp.sum(node_mem, axis=-1),
+                    cpu=cpu,
+                    max_mem_node=jnp.max(node_mem, axis=-1),
+                )
             out = AlgoCarry(x=x, buf=buf, buf_elems=buf_elems)
             ret = (out, metrics)
             ret += (recv,) if recv_counts else ()
@@ -356,14 +357,15 @@ class SyncAlgorithm:
                 want_recv=recv_counts, want_inbox=want_inbox)
 
         # (5) metrics
-        state_elems = lat.size(x).astype(jnp.int32)             # [(B,) N]
-        node_mem = state_elems.astype(acc) + buf_elems.astype(acc)
-        metrics = RoundMetrics(
-            tx=tx,
-            mem=jnp.sum(node_mem, axis=-1),
-            cpu=cpu,
-            max_mem_node=jnp.max(node_mem, axis=-1),
-        )
+        with jax.named_scope("round_metrics"):
+            state_elems = lat.size(x).astype(jnp.int32)         # [(B,) N]
+            node_mem = state_elems.astype(acc) + buf_elems.astype(acc)
+            metrics = RoundMetrics(
+                tx=tx,
+                mem=jnp.sum(node_mem, axis=-1),
+                cpu=cpu,
+                max_mem_node=jnp.max(node_mem, axis=-1),
+            )
         out = AlgoCarry(x=x, buf=buf, buf_elems=buf_elems)
         ret = (out, metrics)
         ret += (recv,) if recv_counts else ()

@@ -28,6 +28,7 @@ asserts this across every algorithm × lattice × topology combination.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import common as kcommon
@@ -256,23 +257,25 @@ def mega_round(algo, x, buf, buf_elems, op_delta, acc_dtype, faults=None,
             jnp.sum(cnt, axis=-1, dtype=jnp.int32)) if want_recv else None
 
     # -- metric arithmetic, in the reference round_step's exact order --------
-    # (1) local update
-    if algo.has_buffer:
-        buf_elems = buf_elems + dsz_op
-    cpu = algo._msum(dsz_op, acc_dtype)
-    # (2) sends: tx counts what an up sender puts on the wire (DESIGN.md §12)
-    send_live = topo.mask if faults is None \
-        else topo.mask & faults.up[..., None]
-    tx = algo._msum(ssend * send_live, acc_dtype)
-    cpu = cpu + tx
-    # (3) ack-gated clear (states/buffers cleared in-kernel)
-    if algo.has_buffer:
-        if faults is None:
-            buf_elems = jnp.zeros_like(buf_elems)
-        else:
-            buf_elems = jnp.where(dlv_mask, 0, buf_elems)
-    # (4) receive
-    cpu = cpu + algo._msum(dsz, acc_dtype)
+    with jax.named_scope("round_metrics"):
+        # (1) local update
+        if algo.has_buffer:
+            buf_elems = buf_elems + dsz_op
+        cpu = algo._msum(dsz_op, acc_dtype)
+        # (2) sends: tx counts what an up sender puts on the wire
+        # (DESIGN.md §12)
+        send_live = topo.mask if faults is None \
+            else topo.mask & faults.up[..., None]
+        tx = algo._msum(ssend * send_live, acc_dtype)
+        cpu = cpu + tx
+        # (3) ack-gated clear (states/buffers cleared in-kernel)
+        if algo.has_buffer:
+            if faults is None:
+                buf_elems = jnp.zeros_like(buf_elems)
+            else:
+                buf_elems = jnp.where(dlv_mask, 0, buf_elems)
+        # (4) receive
+        cpu = cpu + algo._msum(dsz, acc_dtype)
 
     x = unb(xo).reshape(x.shape)
     if algo.has_buffer:

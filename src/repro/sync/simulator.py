@@ -40,6 +40,7 @@ import numpy as np
 from repro.core.lattice import Lattice
 from repro.obs import provenance as prv
 from repro.obs import telemetry as obs
+from repro.obs.trace import TraceLog, maybe_span
 from repro.sync import treeops as T
 from repro.sync.algorithms import AlgoCarry, RoundMetrics, SyncAlgorithm
 from repro.sync.digest import DigestSpec
@@ -151,6 +152,13 @@ def build_round_step(alg: SyncAlgorithm, op_fn, active_rounds: int,
     becomes ``(TelemetryCarry, carry)`` and its ys grow a third
     ``TelemetryChannels`` entry (DESIGN.md §18).
 
+    The step's parts carry ``jax.named_scope``s, which every XLA op
+    keeps in its metadata (the ``tf_op`` of a profiler trace):
+    ``op_stream`` (``op_fn``, its dtype cast and the active-round gate),
+    ``sync`` (``alg.round_step``: the megakernel, classic's epilogue and,
+    nested, its ``round_metrics``) and ``convergence`` (the ``uniform``
+    tracker). Scopes change no value.
+
     ``provenance``: None, or an ``obs.ProvenanceSpec`` — the carry gains
     an OUTERMOST ``ProvenanceCarry`` (around the telemetry wrap when both
     ride: ``(prov, (tele, carry))``) and the ys a trailing
@@ -172,36 +180,43 @@ def build_round_step(alg: SyncAlgorithm, op_fn, active_rounds: int,
         else:
             t, rf = xs[0], views.at_round(xs[1:])
         x_before = carry.x
-        delta = op_fn(carry.x, t)
-        # Confine wide_metrics' x64 tracing to the metric accumulators: an
-        # op_fn with unpinned dtypes would otherwise emit int64/float64
-        # deltas, promote the state, and break the scan carry.
-        delta = jax.tree.map(lambda d, xl: d.astype(xl.dtype), delta, carry.x)
-        # The gate stays rank-minimal (scalar, or the fault masks' own
-        # rank) and where_bot aligns it per leaf — the closure never bakes
-        # in the config extent, so shard_map can run it on local blocks.
-        gate = t < active_rounds
-        if rf is not None:
-            gate = gate & rf.up           # a down node executes no ops
-        delta = T.where_bot(gate, delta, lattice.bottom())
+        with jax.named_scope("op_stream"):
+            delta = op_fn(carry.x, t)
+            # Confine wide_metrics' x64 tracing to the metric accumulators:
+            # an op_fn with unpinned dtypes would otherwise emit
+            # int64/float64 deltas, promote the state, and break the scan
+            # carry.
+            delta = jax.tree.map(lambda d, xl: d.astype(xl.dtype), delta,
+                                 carry.x)
+            # The gate stays rank-minimal (scalar, or the fault masks' own
+            # rank) and where_bot aligns it per leaf — the closure never
+            # bakes in the config extent, so shard_map can run it on local
+            # blocks.
+            gate = t < active_rounds
+            if rf is not None:
+                gate = gate & rf.up       # a down node executes no ops
+            delta = T.where_bot(gate, delta, lattice.bottom())
         want_recv = telemetry is not None and telemetry.redundancy
         inbox = None
-        if want_recv and provenance is not None:
-            carry, metrics, recv, inbox = alg.round_step(
-                carry, delta, faults=rf, recv_counts=True, want_inbox=True)
-        elif want_recv:
-            carry, metrics, recv = alg.round_step(carry, delta, faults=rf,
-                                                  recv_counts=True)
-        elif provenance is not None:
-            recv = None
-            carry, metrics, inbox = alg.round_step(carry, delta, faults=rf,
-                                                   want_inbox=True)
-        else:
-            recv = None
-            carry, metrics = alg.round_step(carry, delta, faults=rf)
+        with jax.named_scope("sync"):
+            if want_recv and provenance is not None:
+                carry, metrics, recv, inbox = alg.round_step(
+                    carry, delta, faults=rf, recv_counts=True,
+                    want_inbox=True)
+            elif want_recv:
+                carry, metrics, recv = alg.round_step(
+                    carry, delta, faults=rf, recv_counts=True)
+            elif provenance is not None:
+                recv = None
+                carry, metrics, inbox = alg.round_step(
+                    carry, delta, faults=rf, want_inbox=True)
+            else:
+                recv = None
+                carry, metrics = alg.round_step(carry, delta, faults=rf)
         if track_convergence:
             # Per-round cluster agreement (time-to-convergence telemetry).
-            uni = cluster_uniform(lattice, carry.x, batched=alg.batched)
+            with jax.named_scope("convergence"):
+                uni = cluster_uniform(lattice, carry.x, batched=alg.batched)
         elif alg.batched:
             lead = jax.tree.leaves(carry.x)[0].shape[0]
             uni = jnp.zeros((lead,), jnp.bool_)
@@ -252,7 +267,7 @@ def run_scan(step, carry0, xs, jit: bool, wide_metrics: bool,
 def run_scan_chunked(step, carry0, xs, jit: bool, wide_metrics: bool,
                      chunk: int, wrap: Optional[Callable] = None,
                      on_chunk: Optional[Callable] = None, start: int = 0,
-                     ys_prefix=None):
+                     ys_prefix=None, trace: Optional[TraceLog] = None):
     """Memory-bounded scan driver (DESIGN.md §16): run the scan in time
     chunks of ``chunk`` rounds with the carry DONATED between chunks and
     per-chunk ys (stacked metrics) offloaded to host.
@@ -275,6 +290,12 @@ def run_scan_chunked(step, carry0, xs, jit: bool, wide_metrics: bool,
     partially-completed scan: rounds ``[0, start)`` are skipped and
     ``ys_prefix`` (their host ys) is prepended to the output.
 
+    ``trace`` (an ``obs.TraceLog``) records per chunk a ``chunk_dispatch``
+    span around the chunk's slice and call (the first one holds the trace,
+    lowering and compile or compile-cache load; args: ``rounds``), a
+    ``chunk_offload`` span around the ys fetch (``bytes``) and a
+    ``chunk_boundary`` instant (``rounds_done``).
+
     Returns ``(carry, ys)`` with ys as host numpy arrays stacked over
     the full time axis.
     """
@@ -296,11 +317,19 @@ def run_scan_chunked(step, carry0, xs, jit: bool, wide_metrics: bool,
     def drive():
         nonlocal carry
         for t0 in range(start, total, chunk):
-            xs_c = jax.tree.map(lambda a: a[t0:t0 + chunk], xs)
-            carry, ys = run(carry, xs_c)
-            chunks.append(jax.device_get(ys))       # offload to host
+            done = min(t0 + chunk, total)
+            with maybe_span(trace, "chunk_dispatch", rounds=done - t0):
+                xs_c = jax.tree.map(lambda a: a[t0:t0 + chunk], xs)
+                carry, ys = run(carry, xs_c)
+            with maybe_span(trace, "chunk_offload") as counts:
+                chunks.append(jax.device_get(ys))   # offload to host
+                if trace is not None:
+                    counts["bytes"] = sum(
+                        a.nbytes for a in jax.tree.leaves(chunks[-1]))
+            if trace is not None:
+                trace.instant("chunk_boundary", rounds_done=done)
             if on_chunk is not None:
-                on_chunk(min(t0 + chunk, total), carry,
+                on_chunk(done, carry,
                          _cat_chunks(chunks) if len(chunks) > 1 else
                          chunks[0])
 

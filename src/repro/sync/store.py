@@ -55,7 +55,6 @@ Workload generators for the store live in ``sync/workloads.py``.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 from pathlib import Path
 from typing import Any, Callable, NamedTuple, Optional, Union
@@ -68,6 +67,7 @@ from repro.checkpoint.checkpointer import Checkpointer
 from repro.core.lattice import BatchWeights, Lattice
 from repro.obs import provenance as prv
 from repro.obs import telemetry as obs
+from repro.obs.trace import maybe_span
 from repro.sync.algorithms import RoundMetrics, SyncAlgorithm, metric_dtype
 from repro.sync.digest import DigestSpec
 from repro.sync.faults import FaultSchedule, FaultViews
@@ -435,11 +435,12 @@ def _reduce_step(step, telemetry=None):
         def red(v):
             return jnp.sum(jnp.where(om, v, 0), keepdims=True)
 
-        metrics = RoundMetrics(
-            tx=red(m.tx), mem=red(m.mem), cpu=red(m.cpu),
-            max_mem_node=jnp.max(jnp.where(om, m.max_mem_node, 0),
-                                 keepdims=True))
-        uni = jnp.all(uni | ~om, keepdims=True)
+        with jax.named_scope("round_metrics"):
+            metrics = RoundMetrics(
+                tx=red(m.tx), mem=red(m.mem), cpu=red(m.cpu),
+                max_mem_node=jnp.max(jnp.where(om, m.max_mem_node, 0),
+                                     keepdims=True))
+            uni = jnp.all(uni | ~om, keepdims=True)
         if telemetry is None:
             return (om, inner), (metrics, uni)
 
@@ -523,20 +524,27 @@ def simulate_store(
     Observability (DESIGN.md §18): ``telemetry=obs.TelemetrySpec()``
     attaches per-object [B, T, N] diagnostic channels (per-shard
     [S, T, N] partials under ``object_metrics=False``); ``trace`` takes
-    an ``obs.TraceLog`` and marks chunk boundaries / checkpoint saves on
-    its timeline. ``provenance=prv.ProvenanceSpec()`` attaches the
-    per-object element-lineage trace (DESIGN.md §19) — per-element
-    coverage/waste matrices are [B, N, E], so it requires
+    an ``obs.TraceLog`` and records the call's host phases as spans
+    under one ``store_call`` (``store_validate``, ``store_build``,
+    ``store_scan`` with its ``chunk_dispatch``/``chunk_offload`` and
+    ``checkpoint_save`` spans and ``chunk_boundary`` instants,
+    ``store_collect``), which also label the host timeline when the call
+    runs under ``jax.profiler``. ``provenance=prv.ProvenanceSpec()``
+    attaches the per-object element-lineage trace (DESIGN.md §19) —
+    per-element coverage/waste matrices are [B, N, E], so it requires
     ``object_metrics=True`` (the lineage matrices cannot be reduced to
     shard partials without losing the per-element views).
     """
-    return _simulate_store(
-        algo, lattice, topo, spec, active_rounds, quiet_rounds, loo=loo,
-        jit=jit, engine=engine, wide_metrics=wide_metrics,
-        track_convergence=track_convergence, shard=shard, digest=digest,
-        layout=layout, chunk_rounds=chunk_rounds, checkpoint=checkpoint,
-        object_metrics=object_metrics, pad_to=pad_to, telemetry=telemetry,
-        provenance=provenance, trace=trace, resume=None)
+    with maybe_span(trace, "store_call", algo=algo, engine=engine,
+                    objects=spec.objects):
+        return _simulate_store(
+            algo, lattice, topo, spec, active_rounds, quiet_rounds, loo=loo,
+            jit=jit, engine=engine, wide_metrics=wide_metrics,
+            track_convergence=track_convergence, shard=shard, digest=digest,
+            layout=layout, chunk_rounds=chunk_rounds, checkpoint=checkpoint,
+            object_metrics=object_metrics, pad_to=pad_to,
+            telemetry=telemetry, provenance=provenance, trace=trace,
+            resume=None)
 
 
 def resume_store(
@@ -593,13 +601,16 @@ def resume_store(
             raise ValueError(
                 f"checkpoint step {step} under {ckpt.dir} records no "
                 f"chunk_rounds — pass chunk_rounds= explicitly")
-    return _simulate_store(
-        algo, lattice, topo, spec, active_rounds, quiet_rounds, loo=loo,
-        jit=jit, engine=engine, wide_metrics=wide_metrics,
-        track_convergence=track_convergence, shard=shard, digest=digest,
-        layout=layout, chunk_rounds=chunk_rounds, checkpoint=ckpt,
-        object_metrics=object_metrics, pad_to=pad_to, telemetry=telemetry,
-        provenance=provenance, trace=trace, resume=(ckpt, step, extra))
+    with maybe_span(trace, "store_call", algo=algo, engine=engine,
+                    objects=spec.objects, resume_round=step):
+        return _simulate_store(
+            algo, lattice, topo, spec, active_rounds, quiet_rounds, loo=loo,
+            jit=jit, engine=engine, wide_metrics=wide_metrics,
+            track_convergence=track_convergence, shard=shard, digest=digest,
+            layout=layout, chunk_rounds=chunk_rounds, checkpoint=ckpt,
+            object_metrics=object_metrics, pad_to=pad_to,
+            telemetry=telemetry, provenance=provenance, trace=trace,
+            resume=(ckpt, step, extra))
 
 
 def _simulate_store(algo, lattice, topo, spec, active_rounds, quiet_rounds,
@@ -625,9 +636,10 @@ def _simulate_store(algo, lattice, topo, spec, active_rounds, quiet_rounds,
     n = topo.num_nodes
 
     # -- eager validation (before any compile/alloc) -------------------------
-    if spec.x0 is not None:
-        _validate_x0(spec.x0, lattice, n, b)
-    _validate_op_fn(spec.op_fn, spec.x0, lattice, n, b)
+    with maybe_span(trace, "store_validate"):
+        if spec.x0 is not None:
+            _validate_x0(spec.x0, lattice, n, b)
+        _validate_op_fn(spec.op_fn, spec.x0, lattice, n, b)
 
     # -- object-axis padding geometry ----------------------------------------
     nshard = 1
@@ -673,31 +685,32 @@ def _simulate_store(algo, lattice, topo, spec, active_rounds, quiet_rounds,
             d = _inner(jax.tree.map(lambda a: a[:b], x), t)
             return _pad_tree(d, bot, pad, (n,))
 
-    alg = SyncAlgorithm(name=algo, lattice=lattice, topo=topo, loo=loo,
-                        engine=engine, batch=b_pad, digest=digest,
-                        batch_layout=layout)
-    carry0 = alg.init(x0)
     total = active_rounds + quiet_rounds
-    views = spec.shared_views(topo, total)
-    if track_convergence is None:
-        track_convergence = views is not None
+    with maybe_span(trace, "store_build"):
+        alg = SyncAlgorithm(name=algo, lattice=lattice, topo=topo, loo=loo,
+                            engine=engine, batch=b_pad, digest=digest,
+                            batch_layout=layout)
+        carry0 = alg.init(x0)
+        views = spec.shared_views(topo, total)
+        if track_convergence is None:
+            track_convergence = views is not None
 
-    step = build_round_step(alg, op_fn, active_rounds, views,
-                            track_convergence, telemetry, provenance)
-    x_init = carry0.x
-    if telemetry is not None:
-        carry0 = (obs.init_carry(alg), carry0)
-    if provenance is not None:
-        carry0 = (prv.init_carry(provenance, alg, x_init), carry0)
-    if not object_metrics:
-        # The pad mask rides the carry (not the closure) so it shards
-        # with P("object") like every other carry leaf.
-        step = _reduce_step(step, telemetry)
-        carry0 = (jnp.arange(b_pad) < b, carry0)
-    if views is None:
-        xs = jnp.arange(total)
-    else:
-        xs = (jnp.arange(total), views.recv_ok, views.send_ok, views.up)
+        step = build_round_step(alg, op_fn, active_rounds, views,
+                                track_convergence, telemetry, provenance)
+        x_init = carry0.x
+        if telemetry is not None:
+            carry0 = (obs.init_carry(alg), carry0)
+        if provenance is not None:
+            carry0 = (prv.init_carry(provenance, alg, x_init), carry0)
+        if not object_metrics:
+            # The pad mask rides the carry (not the closure) so it shards
+            # with P("object") like every other carry leaf.
+            step = _reduce_step(step, telemetry)
+            carry0 = (jnp.arange(b_pad) < b, carry0)
+        if views is None:
+            xs = jnp.arange(total)
+        else:
+            xs = (jnp.arange(total), views.recv_ok, views.send_ok, views.up)
 
     wrap = None
     if shard:
@@ -751,33 +764,22 @@ def _simulate_store(algo, lattice, topo, spec, active_rounds, quiet_rounds,
         start = at
 
     # -- run -----------------------------------------------------------------
-    scan_span = trace.span("store_scan", algo=algo, engine=engine,
-                           objects=b, rounds=total) \
-        if trace is not None else contextlib.nullcontext()
-    with scan_span:
+    with maybe_span(trace, "store_scan", algo=algo, engine=engine,
+                    objects=b, rounds=total):
         if chunk_rounds is None:
             carry, ys = run_scan(step, carry0, xs, jit, wide_metrics,
                                  wrap=wrap)
         else:
             on_chunk = None
-            fp = None
             if ckpt is not None:
                 fp = _run_fingerprint(
                     algo, engine, lattice, topo, layout, loo, b, b_pad,
                     total, chunk_rounds, object_metrics, track_convergence,
                     wide_metrics, shard, digest, telemetry, provenance)
-            if ckpt is not None or trace is not None:
 
                 def on_chunk(rounds_done, carry, ys_host):
-                    if trace is not None:
-                        trace.instant("chunk_boundary",
-                                      rounds_done=int(rounds_done))
-                    if ckpt is None:
-                        return
-                    save_span = trace.span(
-                        "checkpoint_save", rounds_done=int(rounds_done)) \
-                        if trace is not None else contextlib.nullcontext()
-                    with save_span:
+                    with maybe_span(trace, "checkpoint_save",
+                                    rounds_done=int(rounds_done)):
                         ckpt.save(rounds_done,
                                   {"carry": jax.device_get(carry),
                                    "ys": ys_host},
@@ -785,47 +787,54 @@ def _simulate_store(algo, lattice, topo, spec, active_rounds, quiet_rounds,
 
             carry, ys = run_scan_chunked(
                 step, carry0, xs, jit, wide_metrics, chunk_rounds, wrap=wrap,
-                on_chunk=on_chunk, start=start, ys_prefix=ys_prefix)
-    metrics, uniform = ys[0], ys[1]
-    channels = ys[2] if telemetry is not None else None
-    prov_channels = ys[-1] if provenance is not None else None
-    if not object_metrics:
-        _, carry = carry
-    prov_carry = None
-    if provenance is not None:
-        prov_carry, carry = carry
-    if telemetry is not None:
-        _, carry = carry
-    sim = collect_result(carry, metrics, uniform, track_convergence,
-                         batched=True, telemetry=telemetry,
-                         channels=channels, provenance=provenance,
-                         prov_carry=prov_carry, prov_channels=prov_channels,
-                         nbrs=topo.nbrs)
+                on_chunk=on_chunk, start=start, ys_prefix=ys_prefix,
+                trace=trace)
+    with maybe_span(trace, "store_collect") as counts:
+        metrics, uniform = ys[0], ys[1]
+        channels = ys[2] if telemetry is not None else None
+        prov_channels = ys[-1] if provenance is not None else None
+        if not object_metrics:
+            _, carry = carry
+        prov_carry = None
+        if provenance is not None:
+            prov_carry, carry = carry
+        if telemetry is not None:
+            _, carry = carry
+        sim = collect_result(carry, metrics, uniform, track_convergence,
+                             batched=True, telemetry=telemetry,
+                             channels=channels, provenance=provenance,
+                             prov_carry=prov_carry,
+                             prov_channels=prov_channels, nbrs=topo.nbrs)
 
-    # -- mask the pad back out ------------------------------------------------
-    if pad:
-        fx = jax.tree.map(lambda a: a[:b], sim.final_x)
-        if object_metrics:
-            sim = sim._replace(
-                tx=sim.tx[:b], mem=sim.mem[:b], cpu=sim.cpu[:b],
-                max_mem_node=sim.max_mem_node[:b], final_x=fx,
-                uniform=None if sim.uniform is None else sim.uniform[:b],
-                telemetry=None if sim.telemetry is None
-                else sim.telemetry.take_lead(b),
-                provenance=None if sim.provenance is None
-                else sim.provenance.take_lead(b))
-        else:
-            sim = sim._replace(final_x=fx)   # metrics already pad-masked
+        # -- mask the pad back out --------------------------------------------
+        if pad:
+            fx = jax.tree.map(lambda a: a[:b], sim.final_x)
+            if object_metrics:
+                sim = sim._replace(
+                    tx=sim.tx[:b], mem=sim.mem[:b], cpu=sim.cpu[:b],
+                    max_mem_node=sim.max_mem_node[:b], final_x=fx,
+                    uniform=None if sim.uniform is None else sim.uniform[:b],
+                    telemetry=None if sim.telemetry is None
+                    else sim.telemetry.take_lead(b),
+                    provenance=None if sim.provenance is None
+                    else sim.provenance.take_lead(b))
+            else:
+                sim = sim._replace(final_x=fx)   # metrics already pad-masked
 
-    fsb = None
-    if spec.weights is not None:
-        # Weighted final-state footprint [B, N]: every irreducible of
-        # object b priced at weights[b] bytes. BatchWeights aligns the
-        # [B] vector against each leaf's own rank (mixed-rank lattices
-        # broadcast per leaf — a single stacked reshape would not).
-        fsb = np.asarray(
-            lattice.wsize(sim.final_x, BatchWeights(jnp.asarray(spec.weights))),
-            np.float64)
+        fsb = None
+        if spec.weights is not None:
+            # Weighted final-state footprint [B, N]: every irreducible of
+            # object b priced at weights[b] bytes. BatchWeights aligns the
+            # [B] vector against each leaf's own rank (mixed-rank lattices
+            # broadcast per leaf — a single stacked reshape would not).
+            fsb = np.asarray(
+                lattice.wsize(sim.final_x,
+                              BatchWeights(jnp.asarray(spec.weights))),
+                np.float64)
+        if trace is not None:
+            # the final states and their footprint came from the device
+            counts["bytes"] = sum(
+                a.nbytes for a in jax.tree.leaves((sim.final_x, fsb)))
     return StoreResult(sim=sim, weights=spec.weights, final_state_bytes=fsb,
                        object_metrics=object_metrics, num_objects=b)
 
