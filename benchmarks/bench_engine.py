@@ -138,7 +138,7 @@ def _build_runner(algo: str, lat, topo, op_fn, rounds: int, quiet: int,
     way ``simulate(telemetry=...)`` does."""
     alg = SyncAlgorithm(name=algo, lattice=lat, topo=topo, engine=engine)
     carry0 = alg.init(None)
-    step = simulator.build_round_step(alg, op_fn, rounds, None, False,
+    step = simulator.build_round_step(alg, op_fn, rounds, False, False,
                                       telemetry)
     if telemetry is not None:
         carry0 = (obs_telemetry.init_carry(alg), carry0)

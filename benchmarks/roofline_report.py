@@ -44,7 +44,7 @@ def _round_fn(algo, lat, topo, op_fn, engine):
 
     alg = SyncAlgorithm(name=algo, lattice=lat, topo=topo, engine=engine)
     carry0 = alg.init(None)
-    step = simulator.build_round_step(alg, op_fn, 1, None, False)
+    step = simulator.build_round_step(alg, op_fn, 1, False, False)
     return alg, carry0, step, jnp.int32(0)
 
 

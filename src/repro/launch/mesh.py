@@ -57,10 +57,11 @@ def _shard_axis_scan(run, batch: int, mesh, axis: str, what: str,
                      xs_batched: bool):
     """Shard the leading batch axis of a scan callable across ``mesh``.
 
-    ``run(carry0, xs)``: every carry/output-carry leaf has the batch axis
-    at 0, scan ys (stacked metrics) are time-major with the batch axis at
-    1, and ``xs`` is either the round-index array (replicated) or a tuple
-    ``(t, *masks)``. ``xs_batched`` says whether the mask tails carry the
+    ``run(carry0, xs, *operands)``: every carry/output-carry leaf has the
+    batch axis at 0, scan ys (stacked metrics) are time-major with the
+    batch axis at 1, ``xs`` is either the round-index array (replicated)
+    or a tuple ``(t, *masks)``, and ``operands`` (an op stream's tables)
+    replicate. ``xs_batched`` says whether the mask tails carry the
     batch axis at 1 (the sweep's per-cell [T, B, N, P] stacks) or are
     shared by every batch entry and replicate (the store's [T, 1, N, P]
     broadcast views, DESIGN.md §15). Batch entries never communicate, so
@@ -86,19 +87,20 @@ def _shard_axis_scan(run, batch: int, mesh, axis: str, what: str,
     P = jax.sharding.PartitionSpec
     cfg0, cfg1, rep = P(axis), P(None, axis), P()
 
-    def wrapped(carry0, xs):
+    def wrapped(carry0, xs, *operands):
         carry_spec = jax.tree.map(lambda _: cfg0, carry0)
         if isinstance(xs, tuple):
             tail = cfg1 if xs_batched else rep
             xs_spec = (rep,) + tuple(tail for _ in xs[1:])
         else:
             xs_spec = rep
-        out_carry, out_ys = jax.eval_shape(run, carry0, xs)
+        operands_spec = jax.tree.map(lambda _: rep, operands)
+        out_carry, out_ys = jax.eval_shape(run, carry0, xs, *operands)
         out_specs = (jax.tree.map(lambda _: cfg0, out_carry),
                      jax.tree.map(lambda _: cfg1, out_ys))
         return jax.shard_map(
-            run, mesh=mesh, in_specs=(carry_spec, xs_spec),
-            out_specs=out_specs, check_vma=False)(carry0, xs)
+            run, mesh=mesh, in_specs=(carry_spec, xs_spec) + operands_spec,
+            out_specs=out_specs, check_vma=False)(carry0, xs, *operands)
 
     return wrapped
 

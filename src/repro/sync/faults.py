@@ -72,10 +72,6 @@ class FaultViews(NamedTuple):
     send_ok: jnp.ndarray
     up: jnp.ndarray
 
-    def at_round(self, t_slice) -> RoundFaults:
-        return RoundFaults(recv_ok=t_slice[0], send_ok=t_slice[1],
-                           up=t_slice[2])
-
 
 @dataclasses.dataclass(frozen=True)
 class FaultSchedule:

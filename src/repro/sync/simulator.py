@@ -30,7 +30,7 @@ states carry explicit dtypes). Set ``wide_metrics=False`` to opt out.
 
 from __future__ import annotations
 
-import dataclasses
+import contextlib
 from typing import Any, Callable, NamedTuple, Optional
 
 import jax
@@ -44,7 +44,7 @@ from repro.obs.trace import TraceLog, maybe_span
 from repro.sync import treeops as T
 from repro.sync.algorithms import AlgoCarry, RoundMetrics, SyncAlgorithm
 from repro.sync.digest import DigestSpec
-from repro.sync.faults import FaultSchedule
+from repro.sync.faults import FaultSchedule, RoundFaults
 from repro.sync.topology import Topology
 
 
@@ -136,7 +136,7 @@ def converged(lattice: Lattice, final_x) -> bool:
 
 
 def build_round_step(alg: SyncAlgorithm, op_fn, active_rounds: int,
-                     views, track_convergence: bool, telemetry=None,
+                     faulty: bool, track_convergence: bool, telemetry=None,
                      provenance=None):
     """Build the pure ``lax.scan`` body for one op+sync round.
 
@@ -145,8 +145,9 @@ def build_round_step(alg: SyncAlgorithm, op_fn, active_rounds: int,
     per-round program in both cases, which is what keeps every sweep cell
     bit-identical to its single-run equivalent.
 
-    ``views``: None, or a ``FaultViews``-like triple whose ``at_round``
-    slices the per-round masks out of the scan xs tail.
+    ``faulty``: the scan's xs are ``(t, recv_ok, send_ok, up)`` (a
+    ``FaultViews`` rides them) rather than the round index alone; the
+    step reads each round's ``RoundFaults`` from the xs tail.
 
     ``telemetry``: None, or an ``obs.TelemetrySpec`` — the step's carry
     becomes ``(TelemetryCarry, carry)`` and its ys grow a third
@@ -175,10 +176,10 @@ def build_round_step(alg: SyncAlgorithm, op_fn, active_rounds: int,
             prov, carry = carry
         if telemetry is not None:
             tele, carry = carry
-        if views is None:
-            t, rf = xs, None
+        if faulty:
+            t, rf = xs[0], RoundFaults(*xs[1:])
         else:
-            t, rf = xs[0], views.at_round(xs[1:])
+            t, rf = xs, None
         x_before = carry.x
         with jax.named_scope("op_stream"):
             delta = op_fn(carry.x, t)
@@ -241,47 +242,65 @@ def build_round_step(alg: SyncAlgorithm, op_fn, active_rounds: int,
     return step
 
 
-def run_scan(step, carry0, xs, jit: bool, wide_metrics: bool,
-             wrap: Optional[Callable] = None):
-    """Host wrapper around the jitted scan: jit + the x64 metric context.
+def scan_program(step_of: Callable, jit: bool,
+                 wrap: Optional[Callable] = None, donate: bool = False):
+    """The scan as one program ``run(carry0, xs, operands)``.
 
-    ``wrap`` optionally post-processes the scan callable ``run(c0, xs)``
-    before jit (the sweep engine uses it to shard the config axis across
-    devices via ``launch.mesh.shard_sweep_scan``); xs stay an explicit
-    argument so wrappers can assign them shardings.
+    ``step_of(operands)`` builds the ``lax.scan`` body from the program's
+    operand arguments (an op stream's tables, DESIGN.md §16); it runs
+    while ``run`` traces. ``wrap`` optionally post-processes ``run``
+    before jit (``launch.mesh.shard_sweep_scan`` / ``shard_store_scan``
+    shard its batch axis and replicate the operands); xs stay an explicit
+    argument so wrappers can assign them shardings. ``donate`` hands the
+    input carry's buffers to the output carry (``run_scan_chunked``).
     """
 
-    def run(c0, xs_):
-        return jax.lax.scan(step, c0, xs_)
+    def run(c0, xs_, operands):
+        return jax.lax.scan(step_of(operands), c0, xs_)
 
     if wrap is not None:
         run = wrap(run)
     if jit:
-        run = jax.jit(run)
-    if wide_metrics:
-        with jax.enable_x64(True):
-            return run(carry0, xs)
-    return run(carry0, xs)
+        run = jax.jit(run, donate_argnums=0 if donate else ())
+    return run
 
 
-def run_scan_chunked(step, carry0, xs, jit: bool, wide_metrics: bool,
-                     chunk: int, wrap: Optional[Callable] = None,
-                     on_chunk: Optional[Callable] = None, start: int = 0,
-                     ys_prefix=None, trace: Optional[TraceLog] = None):
+def metric_context(wide_metrics: bool):
+    """The x64 context metrics are traced and run in (DESIGN.md §10)."""
+    return jax.enable_x64(True) if wide_metrics else contextlib.nullcontext()
+
+
+def run_scan(step, carry0, xs, jit: bool, wide_metrics: bool,
+             wrap: Optional[Callable] = None):
+    """Host wrapper around the jitted scan: jit + the x64 metric context.
+    ``wrap`` is ``scan_program``'s."""
+    run = scan_program(lambda _: step, jit, wrap)
+    with metric_context(wide_metrics):
+        return run(carry0, xs, ())
+
+
+def run_scan_chunked(run, carry0, xs, wide_metrics: bool, chunk: int,
+                     operands=(), on_chunk: Optional[Callable] = None,
+                     start: int = 0, ys_prefix=None,
+                     trace: Optional[TraceLog] = None):
     """Memory-bounded scan driver (DESIGN.md §16): run the scan in time
     chunks of ``chunk`` rounds with the carry DONATED between chunks and
     per-chunk ys (stacked metrics) offloaded to host.
 
+    ``run(carry, xs, operands)`` is the chunk program,
+    ``scan_program(..., donate=True)``; ``operands`` are passed to every
+    chunk as they are.
+
     A single ``lax.scan`` over T rounds materializes its stacked ys on
     device — O(batch × T) for a batched store — and XLA cannot reuse the
     input carry's buffers across the program boundary. Chunking bounds
-    the device-resident ys to O(batch × chunk), and
-    ``jax.jit(..., donate_argnums=0)`` hands each chunk's input carry
-    buffers back to XLA for the output carry, so peak device memory is
-    O(carry + chunk), independent of T. The per-round program is the
-    same ``step`` a monolithic scan would run and the carry threads
-    through unchanged, so the result is bit-identical to ``run_scan``
-    (states and all metrics) — asserted by ``tests/test_store.py``.
+    the device-resident ys to O(batch × chunk), and the donated carry
+    hands each chunk's input carry buffers back to XLA for the output
+    carry, so peak device memory is O(carry + chunk), independent of T.
+    The per-round program is the same ``step`` a monolithic scan would
+    run and the carry threads through unchanged, so the result is
+    bit-identical to ``run_scan`` (states and all metrics) — asserted by
+    ``tests/test_store.py``.
 
     ``on_chunk(rounds_done, carry, ys_host)`` fires after every chunk
     with the device carry (safe to fetch: the NEXT chunk call is what
@@ -291,10 +310,10 @@ def run_scan_chunked(step, carry0, xs, jit: bool, wide_metrics: bool,
     ``ys_prefix`` (their host ys) is prepended to the output.
 
     ``trace`` (an ``obs.TraceLog``) records per chunk a ``chunk_dispatch``
-    span around the chunk's slice and call (the first one holds the trace,
-    lowering and compile or compile-cache load; args: ``rounds``), a
-    ``chunk_offload`` span around the ys fetch (``bytes``) and a
-    ``chunk_boundary`` instant (``rounds_done``).
+    span around the chunk's slice and call (the first one of a program
+    not run before holds the trace, lowering and compile or compile-cache
+    load; args: ``rounds``), a ``chunk_offload`` span around the ys fetch
+    (``bytes``) and a ``chunk_boundary`` instant (``rounds_done``).
 
     Returns ``(carry, ys)`` with ys as host numpy arrays stacked over
     the full time axis.
@@ -302,25 +321,14 @@ def run_scan_chunked(step, carry0, xs, jit: bool, wide_metrics: bool,
     if chunk < 1:
         raise ValueError(f"chunk must be >= 1, got {chunk}")
     total = int(jax.tree.leaves(xs)[0].shape[0])
-
-    def run(c0, xs_):
-        return jax.lax.scan(step, c0, xs_)
-
-    if wrap is not None:
-        run = wrap(run)
-    if jit:
-        run = jax.jit(run, donate_argnums=0)
-
     chunks = [] if ys_prefix is None else [ys_prefix]
     carry = carry0
-
-    def drive():
-        nonlocal carry
+    with metric_context(wide_metrics):
         for t0 in range(start, total, chunk):
             done = min(t0 + chunk, total)
             with maybe_span(trace, "chunk_dispatch", rounds=done - t0):
                 xs_c = jax.tree.map(lambda a: a[t0:t0 + chunk], xs)
-                carry, ys = run(carry, xs_c)
+                carry, ys = run(carry, xs_c, operands)
             with maybe_span(trace, "chunk_offload") as counts:
                 chunks.append(jax.device_get(ys))   # offload to host
                 if trace is not None:
@@ -332,12 +340,6 @@ def run_scan_chunked(step, carry0, xs, jit: bool, wide_metrics: bool,
                 on_chunk(done, carry,
                          _cat_chunks(chunks) if len(chunks) > 1 else
                          chunks[0])
-
-    if wide_metrics:
-        with jax.enable_x64(True):
-            drive()
-    else:
-        drive()
     if not chunks:
         raise ValueError(f"nothing to run: start={start} >= total={total}")
     return carry, _cat_chunks(chunks) if len(chunks) > 1 else chunks[0]
@@ -457,7 +459,7 @@ def simulate(
     if track_convergence is None:
         track_convergence = faults is not None
 
-    step = build_round_step(alg, op_fn, active_rounds, views,
+    step = build_round_step(alg, op_fn, active_rounds, views is not None,
                             track_convergence, telemetry, provenance)
     if views is None:
         xs = jnp.arange(total)

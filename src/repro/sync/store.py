@@ -55,7 +55,10 @@ Workload generators for the store live in ``sync/workloads.py``.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import hashlib
+import json
 from pathlib import Path
 from typing import Any, Callable, NamedTuple, Optional, Union
 
@@ -76,10 +79,12 @@ from repro.sync.simulator import (
     build_round_step,
     collect_result,
     first_stable_round,
-    run_scan,
+    metric_context,
     run_scan_chunked,
+    scan_program,
 )
 from repro.sync.topology import Topology
+from repro.sync.workloads import OpStream
 
 LAYOUTS = ("rows", "grid")
 
@@ -90,7 +95,10 @@ class StoreSpec:
 
     ``op_fn(x, t) -> deltas`` sees the stacked states ([B, N, ...U]; the
     object axis leads) and returns stacked deltas — per-object op streams
-    live in the object axis (see ``workloads.versioned_slot_op``). Under
+    live in the object axis (see ``workloads.versioned_slot_op``). An
+    ``op_fn`` that is a ``workloads.OpStream`` hands its tables to the
+    program as operands, which lets ``simulate_store`` reuse one compiled
+    program across calls (see there). Under
     object-axis padding on an unsplit axis the op_fn sees exactly the
     unpadded [objects, ...] states (the engine slices the pad off before
     calling and joins ⊥ rows back on); on a multi-device sharded axis it
@@ -503,6 +511,22 @@ def simulate_store(
     ``track_convergence`` defaults on exactly when a fault schedule is
     given.
 
+    Compiled once per shape (DESIGN.md §16): where ``spec.op_fn`` is a
+    ``workloads.OpStream`` (``versioned_slot_op`` is one), its operands
+    are arguments of the jitted scan program, never constants, and the
+    program is kept in a bounded LRU (``program_cache_info()``) under a
+    key of everything that decides its trace: algorithm, engine, ``loo``,
+    ``layout``, ``digest``, the lattice object, the topology's neighbour
+    tables by content, the padded object count, ``active_rounds``, total
+    rounds, ``chunk_rounds``, the metric modes, ``shard`` and the devices,
+    the telemetry and provenance specs, the fault views' presence and
+    shapes, the op stream's ``apply`` and its operands' shapes and
+    dtypes. A later call with an equal key (another seed's count table of
+    the same shape, say) skips tracing, lowering and the compile-cache
+    read. The cache holds ``jax.jit`` wrappers and no operand, carry or
+    result. A plain closure ``op_fn`` (or ``jit=False``) builds its
+    program for the call alone, its tables constants as before.
+
     Scale knobs (DESIGN.md §16; all bit-identical to the plain run):
 
     * ``shard=True`` splits the object axis across the local device mesh
@@ -667,26 +691,12 @@ def _simulate_store(algo, lattice, topo, spec, active_rounds, quiet_rounds,
         _validate_block_op_fn(spec.op_fn, lattice, n, b_pad // nshard,
                               nshard)
 
-    bot = lattice.bottom()
-    op_fn = spec.op_fn
     x0 = spec.x0
-    if pad:
-        x0 = None if x0 is None else _pad_tree(x0, bot, pad, (n,))
-    if pad and nshard == 1:
-        # Unsplit object axis: slice the pad off so op streams (which
-        # may close over [B]-shaped tables) see exactly the unpadded
-        # objects; ⊥ deltas keep the pad rows at bottom forever. When
-        # the axis IS split this wrapper cannot exist (each device holds
-        # a block, not a prefix) — there the shard-agnostic op_fn drives
-        # the pad rows like real objects and the results mask them out
-        # (objects never interact, so evolved pad rows are inert).
-
-        def op_fn(x, t, _inner=spec.op_fn):
-            d = _inner(jax.tree.map(lambda a: a[:b], x), t)
-            return _pad_tree(d, bot, pad, (n,))
+    if pad and x0 is not None:
+        x0 = _pad_tree(x0, lattice.bottom(), pad, (n,))
 
     total = active_rounds + quiet_rounds
-    with maybe_span(trace, "store_build"):
+    with maybe_span(trace, "store_build") as counts:
         alg = SyncAlgorithm(name=algo, lattice=lattice, topo=topo, loo=loo,
                             engine=engine, batch=b_pad, digest=digest,
                             batch_layout=layout)
@@ -694,9 +704,6 @@ def _simulate_store(algo, lattice, topo, spec, active_rounds, quiet_rounds,
         views = spec.shared_views(topo, total)
         if track_convergence is None:
             track_convergence = views is not None
-
-        step = build_round_step(alg, op_fn, active_rounds, views,
-                                track_convergence, telemetry, provenance)
         x_init = carry0.x
         if telemetry is not None:
             carry0 = (obs.init_carry(alg), carry0)
@@ -705,30 +712,44 @@ def _simulate_store(algo, lattice, topo, spec, active_rounds, quiet_rounds,
         if not object_metrics:
             # The pad mask rides the carry (not the closure) so it shards
             # with P("object") like every other carry leaf.
-            step = _reduce_step(step, telemetry)
             carry0 = (jnp.arange(b_pad) < b, carry0)
         if views is None:
             xs = jnp.arange(total)
         else:
             xs = (jnp.arange(total), views.recv_ok, views.send_ok, views.up)
 
-    wrap = None
-    if shard:
-        def wrap(run):
-            return launch_mesh.shard_store_scan(run, b_pad)
+        fp = _run_fingerprint(
+            algo, engine, lattice, topo, layout, loo, b, b_pad, total,
+            chunk_rounds, object_metrics, track_convergence, wide_metrics,
+            shard, digest, telemetry, provenance, active_rounds, views)
+        op, key = spec.op_fn, None
+        if isinstance(op, OpStream):
+            apply, operands = op.apply, op.operands
+            if jit:
+                key = _program_key(fp, lattice, apply, operands, shard)
+        else:       # a closure: its tables are constants of this program
+            apply, operands = (lambda _, x, t: op(x, t)), ()
+
+        def build():
+            wrap = None
+            if shard:
+                def wrap(run):
+                    return launch_mesh.shard_store_scan(run, b_pad)
+            return _chunk_program(
+                alg, apply, b, pad if nshard == 1 else 0, active_rounds,
+                views is not None, track_convergence, telemetry, provenance,
+                object_metrics, wrap, jit, donate=chunk_rounds is not None)
+
+        run, counts["program"] = _PROGRAMS.get(key, build)
 
     # -- resume: restore carry + metric prefix from the bundle ---------------
     start, ys_prefix = 0, None
     if resume is not None:
         ckpt_r, at, extra = resume
-        expect = _run_fingerprint(
-            algo, engine, lattice, topo, layout, loo, b, b_pad, total,
-            chunk_rounds, object_metrics, track_convergence, wide_metrics,
-            shard, digest, telemetry, provenance)
-        bad = [k for k, v in expect.items() if extra.get(k) != v]
+        bad = [k for k, v in fp.items() if extra.get(k) != v]
         if bad:
             detail = ", ".join(
-                f"{k}: saved {extra.get(k)!r} vs requested {expect[k]!r}"
+                f"{k}: saved {extra.get(k)!r} vs requested {fp[k]!r}"
                 for k in bad)
             raise ValueError(
                 f"checkpoint round {at} under {ckpt_r.dir} was written by "
@@ -752,12 +773,9 @@ def _simulate_store(algo, lattice, topo, spec, active_rounds, quiet_rounds,
             ys_like = ys_like + (prv.ProvChannels(
                 *(np.zeros((at, sdim, n), np.int32) for _ in range(3))),)
         like = {"carry": carry0, "ys": ys_like}
-        if wide_metrics:
-            # int64 metric prefixes would silently downcast to int32
-            # outside the x64 context (jnp.asarray in restore).
-            with jax.enable_x64(True):
-                bundle = ckpt_r.restore(at, like)
-        else:
+        # int64 metric prefixes would silently downcast to int32 outside
+        # the x64 context (jnp.asarray in restore).
+        with metric_context(wide_metrics):
             bundle = ckpt_r.restore(at, like)
         carry0 = bundle["carry"]
         ys_prefix = jax.device_get(bundle["ys"])
@@ -767,15 +785,11 @@ def _simulate_store(algo, lattice, topo, spec, active_rounds, quiet_rounds,
     with maybe_span(trace, "store_scan", algo=algo, engine=engine,
                     objects=b, rounds=total):
         if chunk_rounds is None:
-            carry, ys = run_scan(step, carry0, xs, jit, wide_metrics,
-                                 wrap=wrap)
+            with metric_context(wide_metrics):
+                carry, ys = run(carry0, xs, operands)
         else:
             on_chunk = None
             if ckpt is not None:
-                fp = _run_fingerprint(
-                    algo, engine, lattice, topo, layout, loo, b, b_pad,
-                    total, chunk_rounds, object_metrics, track_convergence,
-                    wide_metrics, shard, digest, telemetry, provenance)
 
                 def on_chunk(rounds_done, carry, ys_host):
                     with maybe_span(trace, "checkpoint_save",
@@ -786,9 +800,9 @@ def _simulate_store(algo, lattice, topo, spec, active_rounds, quiet_rounds,
                                   extra=fp)
 
             carry, ys = run_scan_chunked(
-                step, carry0, xs, jit, wide_metrics, chunk_rounds, wrap=wrap,
-                on_chunk=on_chunk, start=start, ys_prefix=ys_prefix,
-                trace=trace)
+                run, carry0, xs, wide_metrics, chunk_rounds,
+                operands=operands, on_chunk=on_chunk, start=start,
+                ys_prefix=ys_prefix, trace=trace)
     with maybe_span(trace, "store_collect") as counts:
         metrics, uniform = ys[0], ys[1]
         channels = ys[2] if telemetry is not None else None
@@ -839,33 +853,169 @@ def _simulate_store(algo, lattice, topo, spec, active_rounds, quiet_rounds,
                        object_metrics=object_metrics, num_objects=b)
 
 
+def _host_topology(topo: Topology) -> Topology:
+    """``topo`` with its tables on the host: a kept program holds no
+    device buffer of the call that built it."""
+    return dataclasses.replace(topo, nbrs=np.asarray(topo.nbrs),
+                               mask=np.asarray(topo.mask),
+                               rev=np.asarray(topo.rev))
+
+
+def _chunk_program(alg, apply, objects, pad, active_rounds, faulty,
+                   track_convergence, telemetry, provenance, object_metrics,
+                   wrap, jit, donate):
+    """The store's scan program ``run(carry, xs, operands)``: the round
+    step over the op program ``apply`` and its operand arguments.
+
+    With ``pad`` ⊥ objects appended to an unsplit object axis the op sees
+    exactly the ``objects`` real rows (op streams may hold [B]-shaped
+    tables) and ⊥ deltas keep the pad rows at bottom forever. On a split
+    axis (``pad=0`` here) each device holds a block, not a prefix, so the
+    shard-agnostic op drives the pad rows like real objects and the
+    results mask them out (objects never interact, so evolved pad rows
+    are inert).
+
+    Everything the program closes over is configuration: ``alg`` (with
+    its topology tables copied to the host), ``apply``, the specs. The
+    call's operands, carry and xs are arguments, so a kept program pins
+    none of them.
+    """
+    alg = dataclasses.replace(alg, topo=_host_topology(alg.topo))
+    n = alg.topo.num_nodes
+
+    def step_of(operands):
+        if pad:
+            bot = alg.lattice.bottom()
+
+            def op_fn(x, t):
+                d = apply(operands, jax.tree.map(lambda a: a[:objects], x),
+                          t)
+                return _pad_tree(d, bot, pad, (n,))
+        else:
+            def op_fn(x, t):
+                return apply(operands, x, t)
+
+        step = build_round_step(alg, op_fn, active_rounds, faulty,
+                                track_convergence, telemetry, provenance)
+        if not object_metrics:
+            step = _reduce_step(step, telemetry)
+        return step
+
+    return scan_program(step_of, jit, wrap, donate)
+
+
+def _program_key(fp: dict, lattice: Lattice, apply, operands,
+                 shard: bool):
+    """Everything that decides the traced chunk program: the run's
+    fingerprint (algorithm, engine, layout, topology tables by content,
+    padding, rounds, chunking, metric modes, telemetry, provenance, fault
+    views), the lattice (its functions by identity), the op program and
+    its operands' structure, shapes and dtypes, and the devices a sharded
+    store spans."""
+    leaves, tree = jax.tree.flatten(operands)
+    return (json.dumps(fp, sort_keys=True), lattice, apply, tree,
+            tuple((tuple(np.shape(a)), str(a.dtype)) for a in leaves),
+            tuple(d.id for d in jax.devices()) if shard else None)
+
+
+class ProgramCacheInfo(NamedTuple):
+    hits: int
+    misses: int
+    size: int
+    maxsize: int
+
+
+class _ProgramCache:
+    """A bounded LRU of jitted store programs by ``_program_key``. It
+    holds ``jax.jit`` wrappers only, so ``jax.clear_caches()`` frees their
+    executables; a key of None (a closure op_fn, or ``jit=False``) builds
+    a program for the call alone."""
+
+    def __init__(self, maxsize: int):
+        self.maxsize = maxsize
+        self._programs = collections.OrderedDict()
+        self.hits = self.misses = 0
+
+    def get(self, key, build):
+        """``(program, "hit" | "miss")``."""
+        run = None if key is None else self._programs.get(key)
+        if run is not None:
+            self._programs.move_to_end(key)
+            self.hits += 1
+            return run, "hit"
+        self.misses += 1
+        run = build()
+        if key is not None:
+            self._programs[key] = run
+            while len(self._programs) > self.maxsize:
+                self._programs.popitem(last=False)
+        return run, "miss"
+
+    def info(self) -> ProgramCacheInfo:
+        return ProgramCacheInfo(self.hits, self.misses, len(self._programs),
+                                self.maxsize)
+
+    def clear(self):
+        self._programs.clear()
+        self.hits = self.misses = 0
+
+
+_PROGRAMS = _ProgramCache(maxsize=8)
+
+
+def program_cache_info() -> ProgramCacheInfo:
+    """Hits and misses of the store's program cache since the process
+    started (or since ``clear_program_cache``), its size and bound. A
+    miss is a call that built its chunk program; a hit reused one."""
+    return _PROGRAMS.info()
+
+
+def clear_program_cache():
+    """Drop every kept store program and zero the counts."""
+    _PROGRAMS.clear()
+
+
+def _topology_digest(topo: Topology) -> str:
+    h = hashlib.sha256()
+    for a in (topo.nbrs, topo.mask, topo.rev):
+        a = np.asarray(a)
+        h.update(f"{a.dtype}{a.shape}".encode())
+        h.update(a.tobytes())
+    return h.hexdigest()
+
+
 def _run_fingerprint(algo, engine, lattice, topo, layout, loo, objects,
                      padded, total_rounds, chunk_rounds, object_metrics,
                      track_convergence, wide_metrics, shard, digest,
-                     telemetry=None, provenance=None) -> dict:
+                     telemetry, provenance, active_rounds, views) -> dict:
     """JSON-safe identity of a store run, written into every chunk
     checkpoint's manifest and verified on resume — restoring a bundle
     into a differently-configured run would type-check (same carry
-    shapes for many configs) but break bit-identity silently."""
+    shapes for many configs) but break bit-identity silently. It is also
+    the JSON part of the chunk program's cache key (``_program_key``)."""
     return {
         "kind": "store",
         "algo": algo,
         "engine": engine,
         "lattice": lattice.name,
         "topology": topo.name,
+        "neighbours": _topology_digest(topo),
         "layout": layout,
         "loo": loo,
         "objects": objects,
         "padded": padded,
+        "active_rounds": active_rounds,
         "total_rounds": total_rounds,
         "chunk_rounds": chunk_rounds,
         "object_metrics": bool(object_metrics),
         "track_convergence": bool(track_convergence),
         "wide_metrics": bool(wide_metrics),
         "shard": bool(shard),
-        "digest": digest is not None,
+        "digest": None if digest is None else dataclasses.asdict(digest),
         # Telemetry/provenance change the carry/ys pytrees, so a bundle
         # written with a different spec cannot restore into this run.
         "telemetry": None if telemetry is None else telemetry.asdict(),
         "provenance": None if provenance is None else provenance.asdict(),
+        "faults": None if views is None
+        else [list(np.shape(a)) for a in views],
     }

@@ -174,7 +174,7 @@ def simulate_sweep(
     if track_convergence is None:
         track_convergence = views is not None
 
-    step = build_round_step(alg, spec.op_fn, active_rounds, views,
+    step = build_round_step(alg, spec.op_fn, active_rounds, views is not None,
                             track_convergence, telemetry, provenance)
     if views is None:
         xs = jnp.arange(total)

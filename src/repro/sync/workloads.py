@@ -23,14 +23,16 @@ Two families of op-stream builders used to live scattered across
   The seed-permutation scheme of the sweep variants (seed 0 = identity =
   the paper-canonical stream) lives here too.
 
-Everything host-side is plain numpy (built once, shipped to the device as
-scan constants); op_fns close over jnp tables only.
+Everything host-side is plain numpy, built once. An op stream ships its
+tables to the device either as the operands of an :class:`OpStream`
+(``versioned_slot_op``: arguments of the compiled program, which the store
+then compiles once per shape) or as constants an op_fn closes over.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Sequence, Tuple
+from typing import Any, Callable, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -166,7 +168,52 @@ def retwis_weights(objects: int) -> np.ndarray:
         np.arange(objects) % 3]
 
 
-def versioned_slot_op(counts: np.ndarray, slots: int) -> Callable:
+@dataclasses.dataclass(frozen=True, eq=False)
+class OpStream:
+    """An op stream whose arrays are operands, not constants.
+
+    ``apply(operands, x, t) -> deltas`` is the pure op program; it closes
+    over no array, and equal ``apply`` values compute the same function of
+    their operands (a frozen dataclass of static parameters, say).
+    ``operands`` is a pytree of device arrays. Called as ``op_fn(x, t)``
+    it is ``apply(operands, x, t)``, so it serves wherever an op_fn does.
+
+    ``simulate_store`` passes the operands to its jitted chunk program as
+    arguments and keys the program on ``apply`` and the operands' shapes
+    and dtypes (DESIGN.md §16): a later call with other operands of the
+    same shapes reuses the compiled program, and the program's cache
+    holds ``apply`` but never the operands.
+    """
+
+    apply: Callable[[Any, Any, jnp.ndarray], Any]
+    operands: Any
+
+    def __call__(self, x, t):
+        return self.apply(self.operands, x, t)
+
+
+@dataclasses.dataclass(frozen=True)
+class _SlotBump:
+    """``versioned_slot_op``'s program over its [T, B, N] count table."""
+
+    slots: int
+
+    def __call__(self, operands, x, t):
+        (upd,) = operands
+        assert x.shape[0] == upd.shape[1], (
+            f"count table built for {upd.shape[1]} objects cannot serve "
+            f"{x.shape[0]} object rows — under shard=True the op sees "
+            "device-local blocks; use a shard-aware op_fn")
+        slots = self.slots
+        cnt = upd[t]                                   # [B, N]
+        ver = jnp.max(x, axis=-1, keepdims=True)       # [B, N, 1]
+        idx = (ver % slots).astype(jnp.int32)
+        sel = (jnp.arange(slots)[None, None, :] - idx) % slots \
+            < cnt[..., None]
+        return jnp.where(sel, x + 1, 0)
+
+
+def versioned_slot_op(counts: np.ndarray, slots: int) -> OpStream:
     """Store op stream over versioned-slot objects (the Retwis model: each
     object is a ``MapLattice(slots, max_int)``).
 
@@ -176,6 +223,14 @@ def versioned_slot_op(counts: np.ndarray, slots: int) -> Callable:
     different nodes hit overlapping slots, which is exactly the contention
     the paper's Zipf workload creates. Returns an op_fn over stacked
     states [B, N, slots] for ``simulate_store`` / ``simulate_sweep``.
+    Rounds past T read the table's last round (the index clamps); the
+    simulator gates quiet rounds' deltas to ⊥.
+
+    The op_fn is an :class:`OpStream`: its one operand is the count table,
+    transposed to [T, B, N] on the device, and its program depends on
+    ``slots`` alone. ``simulate_store`` passes the table to its chunk
+    program as an argument, so stores over different tables of one shape
+    (other seeds, say) share one compiled program.
 
     The count table is indexed by the GLOBAL object axis, so device-local
     blocks (``simulate_store(shard=True)``) are not supported here — a
@@ -183,20 +238,7 @@ def versioned_slot_op(counts: np.ndarray, slots: int) -> Callable:
     (same contract as :func:`gset_unique_sweep_op`).
     """
     upd = jnp.asarray(np.transpose(np.asarray(counts), (0, 2, 1)))  # [T,B,N]
-
-    def op_fn(x, t):
-        assert x.shape[0] == upd.shape[1], (
-            f"count table built for {upd.shape[1]} objects cannot serve "
-            f"{x.shape[0]} object rows — under shard=True the op sees "
-            "device-local blocks; use a shard-aware op_fn")
-        cnt = upd[t]                                   # [B, N]
-        ver = jnp.max(x, axis=-1, keepdims=True)       # [B, N, 1]
-        idx = (ver % slots).astype(jnp.int32)
-        sel = (jnp.arange(slots)[None, None, :] - idx) % slots \
-            < cnt[..., None]
-        return jnp.where(sel, x + 1, 0)
-
-    return op_fn
+    return OpStream(_SlotBump(slots), (upd,))
 
 
 def versioned_slot_cell_op(counts: np.ndarray, obj: int,

@@ -11,8 +11,10 @@ streams are seed-deterministic, op-mix marginals match the spec,
 vectorized update counts match the reference loop).
 """
 
+import gc
 import subprocess
 import sys
+import weakref
 from pathlib import Path
 
 import jax.numpy as jnp
@@ -42,7 +44,14 @@ from repro.sync import (
     simulate_store,
     topology,
 )
+from repro.sync import TelemetrySpec
 from repro.sync import workloads as W
+from repro.obs.trace import TraceLog
+from repro.sync.store import (
+    _ProgramCache,
+    clear_program_cache,
+    program_cache_info,
+)
 
 N, T, Q, B = 7, 5, 8, 3
 
@@ -664,3 +673,156 @@ def test_table1_builders_match_legacy_streams():
     blocks = W.gmap_key_blocks(3, 30, 10)
     assert blocks.sum(axis=1).tolist() == [1, 1, 1]
     assert not (blocks.sum(axis=0) > 1).any()          # disjoint
+
+
+# -- the store's program cache (DESIGN.md §16) ---------------------------------
+
+CB, CN, CSLOTS, CACTIVE, CTOTAL = 4, 6, 8, 3, 5
+
+
+def _cache_counts(seed: int) -> np.ndarray:
+    rng = np.random.default_rng(seed)
+    return rng.integers(0, 3, (CACTIVE, CN, CB)).astype(np.int32)
+
+
+def _relabelled(topo):
+    """The same graph with its nodes renumbered: the topology's name and
+    shapes, other neighbour tables."""
+    adj = np.zeros((CN, CN), bool)
+    for i, row in enumerate(topo.neighbor_lists()):
+        adj[i, row] = True
+    perm = np.roll(np.arange(CN), 1)
+    perm[[0, 1]] = perm[[1, 0]]
+    return topology._from_adj(topo.name, adj[np.ix_(perm, perm)])
+
+
+class _CacheStore:
+    """A Retwis-shaped store small enough for the CPU, whose lattice and
+    topology objects live as long as the fixture, so that calls which
+    change no key field share them."""
+
+    def __init__(self):
+        self.lat = MapLattice(CSLOTS, vl.max_int(), "slots").build()
+        self.topo = topology.partial_mesh(CN, 2)
+
+    def __call__(self, seed=0, active_rounds=CACTIVE, relabel=False,
+                 rebuild_lattice=False, engine="reference", layout="rows",
+                 chunk_rounds=2, telemetry=False, faults=False,
+                 uncached=False, **kw):
+        topo = _relabelled(self.topo) if relabel else self.topo
+        lat = MapLattice(CSLOTS, vl.max_int(), "slots").build() \
+            if rebuild_lattice else self.lat
+        op = W.versioned_slot_op(_cache_counts(seed), CSLOTS)
+        if uncached:
+            # a plain closure: the table is a constant of a program built
+            # for this call alone
+            def op(x, t, _op=op):
+                return _op(x, t)
+        spec = StoreSpec(
+            objects=CB, op_fn=op, weights=np.arange(1.0, CB + 1),
+            faults=FaultSchedule.bernoulli(topo, CTOTAL, 0.3, seed=4)
+            if faults else None)
+        return simulate_store(
+            "bprr", lat, topo, spec, active_rounds, CTOTAL - active_rounds,
+            engine=engine, layout=layout, chunk_rounds=chunk_rounds,
+            track_convergence=True,
+            telemetry=TelemetrySpec() if telemetry else None, **kw)
+
+
+def _assert_call_identical(a, b):
+    for f in ("final_x", "tx", "mem", "cpu", "max_mem_node", "uniform",
+              "tx_bytes"):
+        x, y = np.asarray(getattr(a, f)), np.asarray(getattr(b, f))
+        assert x.dtype == y.dtype, f
+        np.testing.assert_array_equal(x, y, err_msg=f)
+
+
+# one field of the second call's key changed (or, for "other_table", only
+# the count table's contents: the one case that must hit)
+CACHE_CASES = {
+    "other_table": dict(seed=1),
+    "active_rounds": dict(active_rounds=CACTIVE - 1),
+    "topology_neighbours": dict(relabel=True),
+    "lattice": dict(rebuild_lattice=True),
+    "engine": dict(engine="fused"),
+    "layout": dict(layout="grid"),
+    "chunk_rounds": dict(chunk_rounds=3),
+    "telemetry": dict(telemetry=True),
+    "faults": dict(faults=True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CACHE_CASES))
+def test_store_program_compiled_once_per_key(case):
+    """A second call with another count table of the same shape reuses
+    the first call's chunk program; a call that differs in any one key
+    field compiles its own. Every call is bit-identical to the run of a
+    program built for it alone (the table a constant, as a closure)."""
+    store = _CacheStore()
+    clear_program_cache()
+    first = store()
+    log = TraceLog()
+    second = store(trace=log, **CACHE_CASES[case])
+    info = program_cache_info()
+    hit = case == "other_table"
+    assert (info.hits, info.misses) == ((1, 1) if hit else (0, 2))
+    (build,) = [e for e in log.events if e["name"] == "store_build"]
+    assert build["args"]["program"] == ("hit" if hit else "miss")
+    _assert_call_identical(first, store(uncached=True))
+    _assert_call_identical(second,
+                           store(uncached=True, **CACHE_CASES[case]))
+    if hit:
+        assert not np.array_equal(first.tx, second.tx)   # other tables
+
+
+def test_cached_store_resume_bit_identical(tmp_path):
+    """A run killed after its first checkpoint and resumed, both on the
+    program an earlier call compiled, equals the uninterrupted run."""
+    store = _CacheStore()
+    clear_program_cache()
+    full = store()
+    with pytest.raises(KeyboardInterrupt):
+        store(checkpoint=_KilledAfterSaves(tmp_path, die_after=1))
+    spec = StoreSpec(objects=CB,
+                     op_fn=W.versioned_slot_op(_cache_counts(0), CSLOTS),
+                     weights=np.arange(1.0, CB + 1))
+    res = resume_store("bprr", store.lat, store.topo, spec, CACTIVE,
+                       CTOTAL - CACTIVE, checkpoint=tmp_path,
+                       track_convergence=True)
+    assert program_cache_info()[:2] == (2, 1)      # hits, misses
+    _assert_call_identical(full, res)
+    _assert_call_identical(full, store(uncached=True))
+
+
+def test_store_program_cache_holds_no_operand():
+    """After a call, the kept program does not hold the op stream's count
+    table: it dies with the op stream. The cache has a fixed bound."""
+    store = _CacheStore()
+    clear_program_cache()
+    op = W.versioned_slot_op(_cache_counts(0), CSLOTS)
+    table = weakref.ref(op.operands[0])
+    res = simulate_store("bprr", store.lat, store.topo,
+                         StoreSpec(objects=CB, op_fn=op), CACTIVE,
+                         CTOTAL - CACTIVE, chunk_rounds=2)
+    assert program_cache_info().size == 1
+    del op, res
+    gc.collect()
+    assert table() is None
+    assert program_cache_info().maxsize == 8
+
+
+def test_program_cache_evicts_least_recently_used():
+    built = []
+    cache = _ProgramCache(maxsize=2)
+
+    def build(k):
+        return lambda: built.append(k) or k
+
+    assert cache.get("a", build("a")) == ("a", "miss")
+    assert cache.get("b", build("b")) == ("b", "miss")
+    assert cache.get("a", build("a")) == ("a", "hit")
+    assert cache.get("c", build("c")) == ("c", "miss")    # evicts b
+    assert cache.get("b", build("b")) == ("b", "miss")
+    assert cache.get(None, build("x")) == ("x", "miss")   # never kept
+    assert built == ["a", "b", "c", "b", "x"]
+    assert cache.info() == (1, 5, 2, 2)

@@ -259,7 +259,7 @@ def test_chunk_program_names_its_scopes():
     lat, topo, spec = _store()
     alg = SyncAlgorithm(name="bprr", lattice=lat, topo=topo, engine="mega",
                         batch=STORE_B, batch_layout="rows")
-    step = build_round_step(alg, spec.op_fn, 3, None, True)
+    step = build_round_step(alg, spec.op_fn, 3, False, True)
     with jax.enable_x64(True):
         text = jax.jit(lambda c, xs: jax.lax.scan(step, c, xs)).lower(
             alg.init(), jnp.arange(STORE_T)).as_text(debug_info=True)
