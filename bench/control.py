@@ -4,11 +4,12 @@
     python3 bench/control.py --workload <cell> --seeds <n> [<n> ...]
 
 A control is the plain reference put in the program's place with one of
-the configuration's guarantees broken (``reference.CONTROLS``: ``lossy``
-loses the messages of each node's first neighbour slot, ``unsent`` keeps
-node 0's updates from leaving it). For each seed and control it runs the
-reference and the control at the cell's own size on the chip, compares
-the control's outputs with the same code that judges the program
+the configuration's guarantees broken; the cell's deployment module names
+its controls (``CONTROLS``; the Retwis store's ``lossy`` loses the
+messages of each node's first neighbour slot, ``unsent`` keeps node 0's
+updates from leaving it). For each seed and control it runs the reference
+and the control at the cell's own size on the chip, compares the
+control's outputs with the same code that judges the program
 (``check.compare``), and prints each number beside its limit. Every seed
 has to come out not correct. The benchmark's own runs never run this.
 """
@@ -25,32 +26,18 @@ for _p in (ROOT / "src", ROOT):
     if str(_p) not in sys.path:
         sys.path.insert(0, str(_p))
 
-from bench import check, generator, reference, spec  # noqa: E402
-
-
-def outputs(cell: spec.Cell, counts, control=None) -> dict:
-    """The reference's (with ``control``: that control's) outputs in the
-    form ``check.compare`` reads for a program call."""
-    import numpy as np
-
-    c = cell.config
-    out = reference.simulate(counts, nodes=c["nodes"], degree=c["degree"],
-                             slots=c["slots"], algorithm=c["algorithm"],
-                             rounds=cell.rounds, control=control)
-    w = np.asarray(c["weights_bytes"], np.float64)
-    out["weights"] = w[np.arange(c["objects"]) % len(w)]
-    out["tx_bytes"] = out["tx"].astype(np.float64) * out["weights"][:, None]
-    return out
+from bench import check, spec  # noqa: E402
 
 
 def readings(cell: spec.Cell, seed: int) -> dict:
     """``{control: numbers}`` for one seed."""
-    c = cell.config
-    counts = generator.update_counts(cell.traffic, c["objects"], c["nodes"],
-                                     seed % (1 << 64))
-    ref = outputs(cell, counts)
-    return {name: check.compare([outputs(cell, counts, name)], ref)[0]
-            for name in reference.CONTROLS}
+    dep = spec.deployment(cell)
+    schedule = dep.schedule(cell.config, cell.traffic, seed)
+    ref = dep.reference(cell.config, schedule, cell.rounds)
+    return {name: check.compare(
+                [dep.reference(cell.config, schedule, cell.rounds, name)],
+                ref, dep.leq)[0]
+            for name in dep.CONTROLS}
 
 
 def main(argv=None):

@@ -4,11 +4,14 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 from the root of a checkout. A cell of ``BENCHMARK.json`` names a store
-configuration (``bench/configs``) and a traffic mix (``bench/traffic``).
+configuration (``bench/configs``) and a traffic mix (``bench/traffic``);
+the configuration names its deployment module (``bench/deployments``),
+which draws the schedule, builds the store and runs the plain reference
+(``bench/spec.py`` gives the interface).
 
 Set-up: import, check for the chip (a run without a TPU, or with fewer
 chips than the cell asks for, exits 1 and prints no result), draw the
-update-count table from ``--seed``, build the store through the program's
+op schedule from ``--seed``, build the store through the program's
 public API and make one whole warm-up experiment call, which compiles
 every program the window runs. ``setup_s`` is the time from process start
 to the end of that call.
@@ -22,11 +25,12 @@ return, each call ending in host-side results), ``peak_hbm_gb`` (the
 chip's peak bytes in use after the window) and ``setup_s``.
 
 ``--trace 1``: one warm experiment call under the JAX profiler; it reports
-the per-layer metrics (``bench/layers/<name>.py`` reads each), the device
-busy time and window, and a breakdown of device ops and idle gaps.
+the per-layer metrics (``bench/layers/<name>.py`` reads each, from the
+trace's device ops and their named scopes), the device busy time and
+window, and a breakdown of device ops and idle gaps.
 
-After the window, with the program's state freed, the plain reference
-(``bench/reference.py``) runs over the same counts and every call of the
+After the window, with the program's state freed, the deployment's plain
+reference runs over the same schedule and every call of the
 window is compared with it (``bench/check.py``). The numbers compared are
 printed beside their limits as the last lines of standard error and under
 ``checks``, the last key of the result line. JAX's persistent compile cache
@@ -51,7 +55,7 @@ for _p in (ROOT / "src", ROOT):
     if str(_p) not in sys.path:
         sys.path.insert(0, str(_p))
 
-from bench import check, generator, reference, roofline, spec  # noqa: E402
+from bench import check, program_trace, spec  # noqa: E402
 from bench import peaks as peak_table  # noqa: E402
 from bench import xplane  # noqa: E402
 
@@ -138,33 +142,14 @@ class CacheCounter:
 
 
 class Store:
-    """The system under test: the cell's store, called through the
-    program's public entry ``repro.sync.simulate_store``."""
+    """The system under test: the cell's store, built by its deployment
+    module over the schedule and called through the program's public entry
+    ``repro.sync.simulate_store``."""
 
-    def __init__(self, cell: spec.Cell, counts):
-        import numpy as np
-        from repro.core import value_lattices as vl
-        from repro.core.lattice import MapLattice
-        from repro.sync import StoreSpec, topology
-        from repro.sync import workloads
-
-        c = cell.config
-        if c["topology"] != "partial_mesh":
-            raise ValueError(f"unknown topology {c['topology']!r}")
-        if c["value"] != "max_int32":
-            raise ValueError(f"unknown value lattice {c['value']!r}")
-        if c["op_stream"] != "versioned_slot_op":
-            raise ValueError(f"unknown op stream {c['op_stream']!r}")
+    def __init__(self, cell: spec.Cell, deployment, schedule):
         self.cell = cell
-        self.lattice = MapLattice(c["slots"], vl.max_int(),
-                                  c["name"]).build()
-        self.topo = topology.partial_mesh(c["nodes"], c["degree"])
-        w = np.asarray(c["weights_bytes"], np.float64)
-        self.weights = w[np.arange(c["objects"]) % len(w)]
-        self.spec = StoreSpec(
-            objects=c["objects"],
-            op_fn=workloads.versioned_slot_op(counts, c["slots"]),
-            weights=self.weights)
+        self.lattice, self.topo, self.spec = deployment.store(cell.config,
+                                                              schedule)
 
     def __call__(self, trace=None):
         from repro.sync import simulate_store
@@ -180,11 +165,13 @@ class Store:
 
 def timed_window(store: Store, seconds: float):
     """Back-to-back calls while less than ``seconds`` have passed. Returns
-    ``(results, raised, elapsed_s)``."""
-    results, raised = [], []
+    ``(results, raised, elapsed_s, call_s)``, ``call_s`` the seconds of each
+    call."""
+    results, raised, call_s = [], [], []
     t0 = time.perf_counter()
     t_end = t0
     while time.perf_counter() - t0 < seconds:
+        t_call = time.perf_counter()
         try:
             results.append(store())
         except Exception as e:              # a failed call ends the window
@@ -192,7 +179,8 @@ def timed_window(store: Store, seconds: float):
             break
         finally:
             t_end = time.perf_counter()
-    return results, raised, t_end - t0
+            call_s.append(t_end - t_call)
+    return results, raised, t_end - t0, call_s
 
 
 class _WallClock:
@@ -211,8 +199,9 @@ class _WallClock:
 
 def traced_call(store: Store):
     """One call under the profiler. Returns ``(result, raised, profile,
-    host)``: ``host`` holds the call's host spans on the profiler's clock
-    and its seconds of lowering and compiling."""
+    host)``: ``host`` holds the call's host spans on the profiler's clock,
+    its seconds of lowering and compiling, and the ``tf_op`` scope of each
+    device op (``program_trace.op_scopes``)."""
     import glob
     import tempfile
 
@@ -247,6 +236,7 @@ def traced_call(store: Store):
         if len(files) != 1:
             raise RuntimeError(f"expected one trace file, found {files}")
         profile = xplane.load(files[0])
+        scopes = program_trace.op_scopes(files[0])
     ann = xplane.host_events(profile, CALL_ANNOTATION)
     if not ann:
         raise RuntimeError("the trace holds no call annotation")
@@ -263,6 +253,7 @@ def traced_call(store: Store):
                   if n in HOST_EVENTS],
         "instants": [],
         "phases": [],
+        "scopes": scopes,
     }
     for ev in tlog.events:
         at = ns(clock.t0 + ev["ts"] / 1e6)
@@ -279,19 +270,21 @@ def traced_call(store: Store):
     return result, raised, profile, host
 
 
-def layer_metrics(cell: spec.Cell, device, host: dict, kind: str) -> dict:
+def layer_metrics(cell: spec.Cell, deployment, device, host: dict,
+                  kind: str) -> dict:
     """Each per-layer metric of the cell, read by its own file; a reader
-    that finds nothing returns None and the metric is left out."""
-    c = cell.config
+    that finds nothing returns None and the metric is left out.
+    ``ctx["scope_s"](*names)`` gives the device seconds of the ops under
+    any of the ``jax.named_scope``s ``names``."""
     ctx = {
         "rounds": cell.rounds,
         "device": device,
         "kernel": KERNEL,
         "lower_s": host["lower_s"],
-        "round_bytes": roofline.round_bytes(
-            c["algorithm"], c["objects"], c["nodes"], c["degree"],
-            c["slots"]),
+        "round_bytes": deployment.round_bytes(cell.config),
         "peaks": peak_table.peaks(kind),
+        "scope_s": lambda *names: program_trace.scope_s(
+            device, host["scopes"], names),
     }
     out = {}
     for m in cell.per_layer:
@@ -327,9 +320,10 @@ def execute(cell: spec.Cell, seed: int, seconds: float, trace: int,
     import jax
 
     c = cell.config
-    counts = generator.update_counts(cell.traffic, c["objects"], c["nodes"],
-                                     seed % (1 << 64))
-    store = Store(cell, counts)
+    dep = spec.deployment(cell)
+    schedule = dep.schedule(c, cell.traffic, seed)
+    store = Store(cell, dep, schedule)
+    objects = store.spec.objects
     with CacheCounter() as cache:
         store()                              # warm-up: compiles the window
     setup_s = time.perf_counter() - t_start
@@ -343,16 +337,18 @@ def execute(cell: spec.Cell, seed: int, seconds: float, trace: int,
         device = xplane.device_time(profile, *host["window"])
         if device.planes == 0 or device.busy_s <= 0:
             raise RuntimeError("the trace shows no device operation")
-        metrics = layer_metrics(cell, device, host, devices[0].device_kind)
+        metrics = layer_metrics(cell, dep, device, host,
+                                devices[0].device_kind)
         dev_extra = {"busy_s": device.busy_s, "window_s": device.window_s}
         extra["breakdown"] = breakdown(device, host)
     else:
-        results, raised, elapsed = timed_window(store, seconds)
-        log(f"bench: {len(results)} calls in {elapsed:.3f} s")
+        results, raised, elapsed, call_s = timed_window(store, seconds)
+        log(f"bench: {len(results)} calls in {elapsed:.3f} s, each "
+            + " ".join(f"{s:.3f}" for s in call_s))
     peak = memory_peak_bytes(devices[0])
     if not trace:
         e2e = {"object_rounds_per_s":
-               len(results) * c["objects"] * cell.rounds / elapsed,
+               len(results) * objects * cell.rounds / elapsed,
                "setup_s": setup_s}
         if peak is not None:
             e2e["peak_hbm_gb"] = peak / 1e9
@@ -361,13 +357,11 @@ def execute(cell: spec.Cell, seed: int, seconds: float, trace: int,
     for msg in raised:
         log(f"bench: a call raised {msg}")
 
-    outs = [check.call_outputs(r, store.weights) for r in results]
+    outs = [check.call_outputs(r) for r in results]
     del results, store
     jax.clear_caches()                       # free the program's executables
-    ref = reference.simulate(counts, nodes=c["nodes"], degree=c["degree"],
-                             slots=c["slots"], algorithm=c["algorithm"],
-                             rounds=cell.rounds)
-    numbers, bad_calls = check.compare(outs, ref)
+    ref = dep.reference(c, schedule, cell.rounds)
+    numbers, bad_calls = check.compare(outs, ref, dep.leq)
     attempted = len(outs) + len(raised)
     failed = bad_calls + len(raised)
     correct = bool(check.within(numbers) and failed == 0 and attempted > 0)

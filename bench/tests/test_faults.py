@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import check, control, generator, run
+from bench import check, control, run, spec
 
 
 def execute(cell):
@@ -122,14 +122,15 @@ def test_reference_agrees_with_program_engine(small_cell):
 
     for algorithm in ("bprr", "classic"):
         cell = small_cell(algorithm, objects=42, nodes=8, rounds=9, active=5)
-        counts = generator.update_counts(cell.traffic, 42, 8, 11)
-        ref = control.outputs(cell, counts)
+        dep = spec.deployment(cell)
+        counts = dep.schedule(cell.config, cell.traffic, 11)
+        ref = dep.reference(cell.config, counts, cell.rounds)
         res = simulate_store(
             algorithm, MapLattice(64, vl.max_int()).build(),
             topology.partial_mesh(8, 4),
             StoreSpec(objects=42, op_fn=workloads.versioned_slot_op(
-                counts, 64), weights=ref["weights"]),
+                counts, 64), weights=dep.weights(cell.config)),
             5, 4, engine="reference", track_convergence=True)
-        out = check.call_outputs(res, ref["weights"])
-        numbers, bad = check.compare([out], ref)
+        out = check.call_outputs(res)
+        numbers, bad = check.compare([out], ref, dep.leq)
         assert bad == 0 and check.within(numbers), (algorithm, numbers)

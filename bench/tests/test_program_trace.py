@@ -148,3 +148,24 @@ def test_readings_of_the_recorded_call(scoped):
     assert got["entry.idle_s_per_call"] + got["driver.idle_s_per_call"] \
         >= 0.95 * idle
     assert got["idle_gaps"][0][0].startswith("chunk_dispatch at +0.005 s")
+
+
+def test_layer_readers_of_scopes_on_the_recorded_call(scoped, small_cell):
+    """The harness hands the trace's scopes to the layer readers: the two
+    readers of scopes give what ``readings`` gives, next to the readers of
+    op names."""
+    from bench import run, spec
+
+    prof, call, scopes = scoped
+    dev = xplane.device_time(prof, call.start_ns, call.end_ns)
+    cell = small_cell("bprr", objects=600, nodes=50, rounds=ROUNDS, active=2)
+    host = {"window": (call.start_ns, call.end_ns), "lower_s": 0.0,
+            "spans": [], "instants": [], "phases": [], "scopes": scopes}
+    got = {k: v["value"] for k, v in
+           run.layer_metrics(cell, spec.deployment(cell), dev, host,
+                             "TPU v5 lite").items()}
+    want = pt.readings(str(NEW), ROUNDS)
+    assert set(got) == {m["name"] for m in cell.per_layer}
+    for name in ("op_stream.ms_per_round", "round.metrics_ms_per_round"):
+        assert got[name] == pytest.approx(want[name], rel=1e-12)
+    assert got["op_stream.ms_per_round"] == pytest.approx(2.3747455)

@@ -31,6 +31,19 @@ def test_configs_state_their_cuts_and_guarantees():
         assert config["algorithm"] in roofline.BUFFERS
 
 
+@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
+def test_every_configuration_names_its_deployment_file(entry):
+    config = spec.load_json(spec.ROOT / entry["file"])
+    path = spec.DEPLOYMENTS / f"{config['deployment']}.py"
+    assert path.is_file(), path
+    mod = spec.deployment(spec.cell(next(
+        w["name"] for w in BENCH["workloads"]
+        if w["config"] == entry["name"])))
+    for name in ("schedule", "store", "reference", "leq", "round_bytes"):
+        assert callable(getattr(mod, name)), name
+    assert mod.CONTROLS
+
+
 def test_unknown_cell_raises():
     with pytest.raises(KeyError):
         spec.cell("no-such-cell")
