@@ -59,11 +59,13 @@ class Lattice:
     # Pointwise views (universe-axis resolution), used by RR and the kernels:
     irreducible_mask: Callable[[State], Array]    # bool[..., U]
     novel_mask: Callable[[State, State], Array]   # bool[..., U]: ⇓a slots ⋢ b
-    # Dense-kernel dispatch (DESIGN.md §11): the Pallas kernel kind that
-    # implements this lattice's join/Δ on a single dense array, or None if
-    # only the pure-jnp reference engine applies (tuple states, lex orders).
+    # Dense-kernel dispatch (DESIGN.md §11/§17): the Pallas kernel kind that
+    # implements this lattice's join/Δ on dense planes, or None if only the
+    # pure-jnp reference engine applies (products, linear sums).
     #   "max"   — pointwise max order (ℕ-max entries; bool-or as 0/1 max)
     #   "bitor" — bit-packed sets, one irreducible per bit
+    #   "lex"   — (t, v₁, …) tuples of planes under the lexicographic
+    #             order (LWW maps; the megakernel alone implements it)
     kernel_kind: str | None = None
     # Weighted element accounting (DESIGN.md §15): wsize(x, w) sums ``w``
     # over x's non-bottom irreducibles instead of counting them — ``w``
@@ -185,9 +187,9 @@ class MapLattice:
         def is_bottom(a):
             return jnp.all(v.is_bottom(a), axis=-1)
 
-        # The value lattice declares which dense kernel matches its order;
-        # struct-of-arrays points (lex pairs) take the jnp fallback.
-        kind = v.kernel_kind if v.arity == 1 else None
+        # The value lattice declares which dense kernel matches its order
+        # (one plane a point, or the megakernel's (t, v₁, …) planes).
+        kind = v.kernel_kind
 
         return Lattice(
             name=self.name,

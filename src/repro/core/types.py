@@ -224,21 +224,28 @@ class GMap:
 
 @dataclasses.dataclass(frozen=True)
 class LWWMap:
+    """``width`` > 1: each key holds a record of ``width`` int32 planes,
+    the state (t, v₁, …, v_width) under the lexicographic order; ``put``
+    then takes ``width`` values."""
+
     num_keys: int
+    width: int = 1
 
     @property
     def lattice(self) -> Lattice:
-        return MapLattice(self.num_keys, vl.lex_pair(), "lwwmap").build()
+        value = vl.lex_pair() if self.width == 1 else vl.lex(self.width)
+        return MapLattice(self.num_keys, value, "lwwmap").build()
+
+    def _vals(self, val):
+        return (val,) if self.width == 1 else tuple(val)
 
     def put(self, s, key, ts, val):
-        t, v = s
-        return (t.at[key].set(ts), v.at[key].set(val))
+        return tuple(c.at[key].set(w)
+                     for c, w in zip(s, (ts,) + self._vals(val)))
 
     def put_delta(self, s, key, ts, val):
-        t, v = s
-        dt = jnp.zeros_like(t).at[key].set(ts)
-        dv = jnp.zeros_like(v).at[key].set(val)
-        return (dt, dv)
+        return tuple(jnp.zeros_like(c).at[key].set(w)
+                     for c, w in zip(s, (ts,) + self._vals(val)))
 
 
 # ---------------------------------------------------------------------------

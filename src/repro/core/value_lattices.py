@@ -39,10 +39,11 @@ class ValueLattice:
     is_bottom: Callable[[Any], Array]
     # number of arrays making up a point (1 for scalar lattices)
     arity: int = 1
-    # Dense Pallas kernel implementing this pointwise order ("max", "bitor")
-    # or None — propagated to Lattice.kernel_kind for engine dispatch
-    # (DESIGN.md §11). Must only be set when the order really is the
-    # kernel's (e.g. "max" ⇒ join is pointwise max / or on {0, 1}).
+    # Dense Pallas kernel implementing this pointwise order ("max", "bitor",
+    # "lex") or None — propagated to Lattice.kernel_kind for engine dispatch
+    # (DESIGN.md §11/§17). Must only be set when the order really is the
+    # kernel's (e.g. "max" ⇒ join is pointwise max / or on {0, 1}; "lex" ⇒
+    # points are (t, v₁, …) tuples of planes under the lexicographic order).
     kernel_kind: str | None = None
 
 
@@ -70,33 +71,52 @@ def or_bool() -> ValueLattice:
     )
 
 
+def lex_gt(a, b):
+    """Pointwise a > b in the lexicographic order of two points (tuples
+    of planes, the first deciding): the last plane's strict order, then
+    each earlier plane's, from the back."""
+    gt = a[-1] > b[-1]
+    for ai, bi in zip(a[-2::-1], b[-2::-1]):
+        gt = (ai > bi) | ((ai == bi) & gt)
+    return gt
+
+
+def lex(width: int = 1, ts_dtype=jnp.int32, val_dtype=jnp.int32,
+        name: str | None = None) -> ValueLattice:
+    """Lexicographic (version, value₁, …, value_width) — an LWW register
+    whose value is ``width`` planes wide (a record of ``4·width`` bytes in
+    int32 planes). The order is a chain: the newer version wins, a tie is
+    broken by the values in plane order, so the join selects every plane
+    of the larger point (paper Appendix B, Table III: a chain keeps the lex
+    product distributive)."""
+    if width < 1:
+        raise ValueError(f"a lex point needs a value plane, not {width}")
+
+    def bottom(shape):
+        return (jnp.zeros(shape, ts_dtype),) + tuple(
+            jnp.zeros(shape, val_dtype) for _ in range(width))
+
+    def join(a, b):
+        win = lex_gt(a, b)
+        return tuple(jnp.where(win, ai, bi) for ai, bi in zip(a, b))
+
+    def leq(a, b):
+        return jnp.logical_not(lex_gt(a, b))
+
+    def is_bottom(a):
+        out = a[0] == 0
+        for ai in a[1:]:
+            out = out & (ai == 0)
+        return out
+
+    return ValueLattice(
+        name=name or f"lex_{width + 1}", bottom=bottom, join=join, leq=leq,
+        is_bottom=is_bottom, arity=width + 1, kernel_kind="lex",
+    )
+
+
 def lex_pair(ts_dtype=jnp.int32, val_dtype=jnp.int32) -> ValueLattice:
     """Lexicographic pair (version, value) — LWW registers / Cassandra-style
     counters (single-writer principle: the version is a chain, so the lex
     product stays distributive; see paper Appendix B, Table III)."""
-
-    def bottom(shape):
-        return (jnp.zeros(shape, ts_dtype), jnp.zeros(shape, val_dtype))
-
-    def join(a, b):
-        ta, va = a
-        tb, vb = b
-        take_a = ta > tb
-        eq = ta == tb
-        ts = jnp.maximum(ta, tb)
-        val = jnp.where(eq, jnp.maximum(va, vb), jnp.where(take_a, va, vb))
-        return (ts, val)
-
-    def leq(a, b):
-        ta, va = a
-        tb, vb = b
-        return (ta < tb) | ((ta == tb) & (va <= vb))
-
-    def is_bottom(a):
-        ta, va = a
-        return (ta == 0) & (va == 0)
-
-    return ValueLattice(
-        name="lex_pair", bottom=bottom, join=join, leq=leq,
-        is_bottom=is_bottom, arity=2,
-    )
+    return lex(1, ts_dtype, val_dtype, name="lex_pair")

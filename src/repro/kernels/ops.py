@@ -25,9 +25,12 @@ from repro.kernels.common import LANE, SUBLANE
 from repro.kernels.delta_extract import delta_extract_2d
 from repro.kernels.digest import digest_blocks_2d, digest_tile, masked_extract_2d
 from repro.kernels.join import join_2d
-from repro.kernels.lex_join import lex_join_delta_2d
 from repro.kernels.round_recv import ROUND_BLOCK, round_recv_2d
-from repro.kernels.round_step import round_step_2d
+from repro.kernels.round_step import planes, point, round_step_2d, tile_bytes
+
+# The blocks of one megakernel grid step that a wide lex point's default
+# tile may take (double-buffered; the round's values need as much again).
+VMEM_BUDGET = 24 << 20
 
 
 def _tiled_2d(kernel_2d, operands, *, block, interpret, **kw):
@@ -36,7 +39,7 @@ def _tiled_2d(kernel_2d, operands, *, block, interpret, **kw):
 
     Scalar outputs (counts) pass through untouched; array outputs are
     unpadded back to the first operand's shape. Deduplicates the prologs of
-    ``join``/``delta_extract``/``lex_join_delta`` (DESIGN.md §17).
+    ``join``/``delta_extract`` (DESIGN.md §17).
     """
     interpret = interpret_default() if interpret is None else interpret
     shape = n = None
@@ -64,14 +67,6 @@ def delta_extract(d, x, *, kind: str = "max", block=DEFAULT_BLOCK, interpret=Non
     """Fused RR step: returns (Δ(d,x), x ⊔ d, |⇓Δ|)."""
     return _tiled_2d(delta_extract_2d, (d, x), block=block,
                      interpret=interpret, kind=kind)
-
-
-def lex_join_delta(a, b, *, block=DEFAULT_BLOCK, interpret=None):
-    """Fused LWW-map step on lex-pair states a=(ta,va), b=(tb,vb):
-    returns (a ⊔ b, Δ(b, a), |⇓Δ|)."""
-    t, v, dt, dv, cnt = _tiled_2d(lex_join_delta_2d, (*a, *b), block=block,
-                                  interpret=interpret)
-    return ((t, v), (dt, dv), cnt)
 
 
 def buffer_fold(buf, *, kind: str = "max", block=FOLD_BLOCK, interpret=None,
@@ -247,17 +242,31 @@ def _lane_tiles(u: int):
 
 def sync_round_block(b: int, n: int, u: int, *, p: int, k: int,
                      kind: str = "max", layout: str = "grid",
-                     interpret=None, tune_bench=None):
+                     interpret=None, tune_bench=None, n_planes: int = 1):
     """Resolve the megakernel tile config (g, bn) for the given shapes —
     autotuned (kernels.common.tuned_block) with a heuristic default.
 
     ``b``: configs, ``n``: nodes, ``u``: flattened universe, ``p``: degree,
-    ``k``: buffer slots (0 = state-based). Every tile holds the whole node
-    axis. Returns ``((g, bn), source)``.
+    ``k``: buffer slots (0 = state-based), ``n_planes``: planes a point (a
+    lex point's 1 + value planes). Every tile holds the whole node axis.
+    Points of more than two planes take one config a tile and the widest
+    lane tile that divides ``u`` and whose blocks fit ``VMEM_BUDGET``.
+    Returns ``((g, bn), source)``.
     """
     interpret = interpret_default() if interpret is None else interpret
     np_ = -(-n // SUBLANE) * SUBLANE             # VMEM rows per config
     bn_opts = _lane_tiles(u)
+    if n_planes > 2:
+        fits = [w for w in bn_opts if u % w == 0 and tile_bytes(
+            n_planes, k, p, 1, n, w, True) <= VMEM_BUDGET]
+        default = (1, max(fits) if fits else min(bn_opts))
+        key = (common.backend_key(), kind, f"a{n_planes}", f"p{p}", f"k{k}",
+               layout, f"n{np_}", f"b{common.shape_bucket(b)}",
+               f"u{common.shape_bucket(u)}")
+        return common.tuned_block("round_step", key,
+                                  [default] + [(1, w) for w in fits
+                                               if (1, w) != default],
+                                  tune_bench)
     if layout == "rows" and b > 1:
         g_opts = sorted({min(b, g) for g in (1, max(1, 64 // np_),
                                              max(1, 256 // np_))})
@@ -287,6 +296,9 @@ def sync_round(delta, x, buf, active, delivered, *, nbrs, rev,
       ignored without a buffer
     * ``nbrs``/``rev``: the topology's static [N, P] routing tables
 
+    ``kind="lex"`` takes ``delta``, ``x`` and ``buf`` as (t, v₁, …) tuples
+    of such arrays and returns x', buf' and the inbox as such tuples.
+
     Returns ``(x', buf', inbox, dsz_op, xsz, ssend, cnt, dsz)``: states and
     buffers in the input dtype; ``inbox`` [P, B, N, U] — the active-masked
     received δ-groups, emitted for the classic/bp flavors
@@ -299,14 +311,15 @@ def sync_round(delta, x, buf, active, delivered, *, nbrs, rev,
     masking, novel counts, received sizes).
     """
     interpret = interpret_default() if interpret is None else interpret
-    b, n, u = x.shape
+    b, n, u = planes(x, kind)[0].shape
     p = nbrs.shape[-1]
     has_buffer = buf is not None
-    k = buf.shape[0] if has_buffer else 0
+    k = planes(buf, kind)[0].shape[0] if has_buffer else 0
     emit_inbox = (has_buffer and not extracts) or want_inbox
     if block is None:
         block, _ = sync_round_block(b, n, u, p=p, k=k, kind=kind,
-                                    layout=layout, interpret=interpret)
+                                    layout=layout, interpret=interpret,
+                                    n_planes=len(planes(x, kind)))
     g, bn = block
     g = max(1, min(g, b))
     bn = min(bn, u)
@@ -314,12 +327,13 @@ def sync_round(delta, x, buf, active, delivered, *, nbrs, rev,
     u_pad = -(-u // bn) * bn
     routes = _routes_for(nbrs, rev)
 
-    orig_dtype = x.dtype
-    cast = BOOL_VIEW if orig_dtype == jnp.bool_ else orig_dtype
+    dtypes = [c.dtype for c in planes(x, kind)]
 
-    def pad(a, lead=()):
-        return jnp.pad(a.astype(cast),
-                       lead + ((0, b_pad - b), (0, 0), (0, u_pad - u)))
+    def pad(v, lead=()):
+        return point([jnp.pad(c.astype(BOOL_VIEW if c.dtype == jnp.bool_
+                                       else c.dtype),
+                              lead + ((0, b_pad - b), (0, 0), (0, u_pad - u)))
+                      for c in planes(v, kind)], kind)
 
     d2, x2 = pad(delta), pad(x)
     if has_buffer:
@@ -335,11 +349,16 @@ def sync_round(delta, x, buf, active, delivered, *, nbrs, rev,
         extracts=bool(extracts and has_buffer), block=(g, bn),
         interpret=interpret)
 
-    xo = xo[:b, :n, :u].astype(orig_dtype)
+    def unpad(v, lead=()):
+        cut = (slice(None),) * len(lead) + (slice(b), slice(n), slice(u))
+        return point([c[cut].astype(dt)
+                      for c, dt in zip(planes(v, kind), dtypes)], kind)
+
+    xo = unpad(xo)
     if bo is not None:
-        bo = bo[:, :b, :n, :u].astype(orig_dtype)
+        bo = unpad(bo, ((0, 0),))
     if ib is not None:
-        ib = ib[:, :b, :n, :u].astype(orig_dtype)
+        ib = unpad(ib, ((0, 0),))
 
     def trim(c):
         # [GB, GJ, g, N, C] -> sum universe tiles -> [B, N, C]

@@ -16,7 +16,28 @@ def join(a, b, kind: str = "max"):
         return jnp.maximum(a, b)
     if kind == "bitor":
         return jnp.bitwise_or(a, b)
+    if kind == "lex":                 # (t, v₁, …): the larger point
+        win = _lex_leq(b, a)
+        return tuple(jnp.where(win, ca, cb) for ca, cb in zip(a, b))
     raise ValueError(kind)
+
+
+def _lex_leq(a, b):
+    """a ⊑ b in the lex order, deciding from the first plane: a is below
+    where the first plane that differs is smaller, or none differs."""
+    decided = jnp.zeros(a[0].shape, bool)
+    below = jnp.ones(a[0].shape, bool)
+    for ca, cb in zip(a, b):
+        below = jnp.where(decided, below, ca < cb)
+        decided = decided | (ca != cb)
+    return below | ~decided
+
+
+def lex_delta(d, x):
+    """Δ(d, x) over lex points: d's slots that are not ⊥ and not ⊑ x's."""
+    bottom = jnp.all(jnp.stack([c == 0 for c in d]), axis=0)
+    novel = ~_lex_leq(d, x) & ~bottom
+    return tuple(jnp.where(novel, c, 0) for c in d), novel
 
 
 def delta_extract(d, x, kind: str = "max"):
@@ -29,19 +50,6 @@ def delta_extract(d, x, kind: str = "max"):
         cnt = jnp.sum(jax.lax.population_count(s).astype(jnp.int32))
         return s, jnp.bitwise_or(x, d), cnt
     raise ValueError(kind)
-
-
-def lex_join_delta(ta, va, tb, vb):
-    eq = ta == tb
-    a_wins = ta > tb
-    t = jnp.maximum(ta, tb)
-    v = jnp.where(eq, jnp.maximum(va, vb), jnp.where(a_wins, va, vb))
-    leq_b_a = (tb < ta) | (eq & (vb <= va))
-    bot_b = (tb == 0) & (vb == 0)
-    novel = ~leq_b_a & ~bot_b
-    dt = jnp.where(novel, tb, jnp.zeros_like(tb))
-    dv = jnp.where(novel, vb, jnp.zeros_like(vb))
-    return t, v, dt, dv, jnp.sum(novel.astype(jnp.int32))
 
 
 def round_recv(d_stack, x, kind: str = "max", emit_cov: bool = False):
@@ -99,53 +107,68 @@ def sync_round(delta, x, buf, active, delivered, *, nbrs, rev,
                extracts: bool = False, emit_inbox: bool | None = None):
     """Whole-round oracle for the megakernel (kernels/round_step.py), on the
     same canonical operands as ``ops.sync_round``: delta/x [B, N, U], buf
-    [K, B, N, U] or None, active [B, N, P], delivered [B, N]. Deliberately
+    [K, B, N, U] or None, active [B, N, P], delivered [B, N] (each state
+    operand a (t, v₁, …) tuple of such arrays for kind "lex"). Deliberately
     multi-pass: local join → sends (leave-one-out per-origin) → ack-gated
     clear → routed slot-order receive. ``emit_inbox=None`` keeps the
     classic/bp derivation (buffered, non-extracting); True forces the
     stacked active-masked inbox out regardless (provenance replay)."""
+    lex = kind == "lex"
+
+    def each(fn, *pts):               # fn over every plane of the points
+        return tuple(map(fn, *pts)) if lex else fn(*pts)
+
     p = nbrs.shape[-1]
     dsz_op = _size(delta, kind)
     x = join(x, delta, kind)
     if buf is not None:
-        k = buf.shape[0]
+        k = (buf[0] if lex else buf).shape[0]
         self_slot = k - 1 if per_origin else 0
-        buf = buf.at[self_slot].set(join(buf[self_slot], delta, kind))
+        slot = [each(lambda c: c[o], buf) for o in range(k)]
+        slot[self_slot] = join(slot[self_slot], delta, kind)
         if per_origin:
-            sends = [
-                _fold([buf[o] for o in range(k) if o != j], kind)
-                for j in range(p)]
+            sends = [_fold([slot[o] for o in range(k) if o != j], kind)
+                     for j in range(p)]
         else:
-            sends = [buf[0]] * p
+            sends = [slot[0]] * p
     else:
         sends = [x] * p
     ssend = jnp.stack([_size(s, kind) for s in sends], axis=-1)   # [B, N, P]
     if buf is not None:
-        buf = jnp.where((delivered != 0)[None, :, :, None],
-                        jnp.zeros((), buf.dtype), buf)
+        gone = (delivered != 0)[:, :, None]
+        slot = [each(lambda c: jnp.where(gone, jnp.zeros((), c.dtype), c), s)
+                for s in slot]
     inbox, cnts, dszs = [], [], []
     for q in range(p):
-        d = jnp.stack([sends[int(rev[i, q])][:, int(nbrs[i, q])]
-                       for i in range(x.shape[1])], axis=1)
-        d = jnp.where((active[:, :, q] != 0)[..., None],
-                      d, jnp.zeros((), d.dtype))
+        d = each(lambda *cs: jnp.stack(
+            [cs[int(rev[i, q])][:, int(nbrs[i, q])]
+             for i in range(nbrs.shape[0])], axis=1), *sends)
+        on = (active[:, :, q] != 0)[..., None]
+        d = each(lambda c: jnp.where(on, c, jnp.zeros((), c.dtype)), d)
         if kind == "max":
             novel = d > x
             s = jnp.where(novel, d, jnp.zeros_like(d))
             cnts.append(jnp.sum(novel, axis=-1).astype(jnp.int32))
-        else:
+        elif kind == "bitor":
             s = jnp.bitwise_and(d, jnp.bitwise_not(x))
             cnts.append(_size(s, kind))
+        else:
+            s, novel = lex_delta(d, x)
+            cnts.append(jnp.sum(novel, axis=-1).astype(jnp.int32))
         dszs.append(_size(d, kind))
         inbox.append(d)
         x = join(x, d, kind)
         if buf is not None and extracts:
             tgt = q if per_origin else 0
-            buf = buf.at[tgt].set(join(buf[tgt], s, kind))
+            slot[tgt] = join(slot[tgt], s, kind)
     xsz = _size(x, kind)
+    if buf is not None:
+        buf = each(lambda *cs: jnp.stack(cs, axis=0), *slot)
     emit = (buf is not None and not extracts) if emit_inbox is None \
         else emit_inbox
-    return (x, buf, jnp.stack(inbox, axis=0) if emit else None,
+    return (x, buf,
+            each(lambda *cs: jnp.stack(cs, axis=0), *inbox) if emit
+            else None,
             dsz_op, xsz, ssend,
             jnp.stack(cnts, axis=-1), jnp.stack(dszs, axis=-1))
 
@@ -153,6 +176,9 @@ def sync_round(delta, x, buf, active, delivered, *, nbrs, rev,
 def _size(v, kind: str):
     if kind == "max":
         return jnp.sum((v != 0).astype(jnp.int32), axis=-1, dtype=jnp.int32)
+    if kind == "lex":
+        return jnp.sum(jnp.any(jnp.stack([c != 0 for c in v]), axis=0)
+                       .astype(jnp.int32), axis=-1, dtype=jnp.int32)
     return jnp.sum(jax.lax.population_count(v).astype(jnp.int32), axis=-1,
                    dtype=jnp.int32)
 

@@ -45,59 +45,142 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import interpret_default, pallas_call
+from repro.core.value_lattices import lex_gt
+from repro.kernels.common import SUBLANE, interpret_default, pallas_call
+
+# Scoped VMEM: what a launch gets unless it asks (v5e), and the most a
+# launch asks for (of the core's 128 MiB).
+VMEM_DEFAULT = 16 << 20
+VMEM_MAX = 100 << 20
+
+
+def planes(v, kind: str) -> tuple:
+    """The planes of a state operand of ``kind``: a lex point's (t, v₁, …),
+    or ``(v,)``."""
+    return tuple(v) if kind == "lex" else (v,)
+
+
+def point(cs, kind: str):
+    """The state operand of ``kind`` made of the planes ``cs`` (the inverse
+    of :func:`planes`)."""
+    return tuple(cs) if kind == "lex" else cs[0]
+
+
+def tile_bytes(a: int, k: int, p: int, g: int, n: int, bn: int,
+               emit_inbox: bool) -> int:
+    """VMEM bytes of one grid step's state blocks, double-buffered: δ, x
+    and the K buffer slots in, x', the K slots and (``emit_inbox``) the P
+    inbox slots out, each ``a`` planes of [g, N, bn] 32-bit words with N
+    padded to the sublane."""
+    blocks = a * (3 + 2 * k + (p if emit_inbox else 0))
+    return 2 * blocks * g * (-(-n // SUBLANE) * SUBLANE) * bn * 4
+
+
+def _nonbottom(v):
+    """Where a lex point is not ⊥: some plane is nonzero."""
+    out = v[0] != 0
+    for c in v[1:]:
+        out = out | (c != 0)
+    return out
 
 
 def _count_rows(v, kind: str):
-    """Per-row irreducible count over the lane axis, pinned int32 (jnp.sum
-    would promote under the simulator's x64 metric context)."""
+    """Per-row irreducible count of a point (a tuple of planes) over the
+    lane axis, pinned int32 (jnp.sum would promote under the simulator's
+    x64 metric context). A lex point counts where any plane ≠ 0."""
     if kind == "max":
-        return jnp.sum((v != 0).astype(jnp.int32), axis=-1, dtype=jnp.int32)
-    return jnp.sum(jax.lax.population_count(v).astype(jnp.int32), axis=-1,
-                   dtype=jnp.int32)
+        return jnp.sum((v[0] != 0).astype(jnp.int32), axis=-1,
+                       dtype=jnp.int32)
+    if kind == "bitor":
+        return jnp.sum(jax.lax.population_count(v[0]).astype(jnp.int32),
+                       axis=-1, dtype=jnp.int32)
+    return jnp.sum(_nonbottom(v).astype(jnp.int32), axis=-1, dtype=jnp.int32)
 
 
-def _round_step_kernel(d_ref, x_ref, *refs, g: int, np_: int, p: int, k: int,
-                       kind: str, per_origin: bool, emit_inbox: bool,
+def _join(a, b, kind: str):
+    """Pointwise join of two points (tuples of planes). A lex join selects
+    every plane of the larger point by one mask."""
+    if kind == "max":
+        return (jnp.maximum(a[0], b[0]),)
+    if kind == "bitor":
+        return (jnp.bitwise_or(a[0], b[0]),)
+    win = lex_gt(a, b)
+    return tuple(jnp.where(win, ca, cb) for ca, cb in zip(a, b))
+
+
+def _where(mask, a):
+    """``a`` where ``mask``, ⊥ (0 in every plane) elsewhere."""
+    return tuple(jnp.where(mask, c, jnp.zeros((), c.dtype)) for c in a)
+
+
+def _receive(d, x, kind: str):
+    """One receive slot: ``(Δ(d, x), |⇓Δ| per row, x ⊔ d)``. Novelty is
+    ¬(d ⊑ x) on a non-⊥ slot of d, in the kind's order."""
+    if kind == "max":
+        novel = d[0] > x[0]
+        s = _where(novel, d)
+        cnt = jnp.sum(novel, axis=-1, dtype=jnp.int32)
+        return s, cnt, (jnp.maximum(x[0], d[0]),)
+    if kind == "bitor":
+        s = (jnp.bitwise_and(d[0], jnp.bitwise_not(x[0])),)
+        return s, _count_rows(s, kind), (jnp.bitwise_or(x[0], d[0]),)
+    above = lex_gt(d, x)                  # x ⊔ d is d here, else x
+    novel = above & _nonbottom(d)
+    cnt = jnp.sum(novel, axis=-1, dtype=jnp.int32)
+    return (_where(novel, d), cnt,
+            tuple(jnp.where(above, cd, cx) for cd, cx in zip(d, x)))
+
+
+def _round_step_kernel(*refs, g: int, np_: int, p: int, k: int, kind: str,
+                       a: int, per_origin: bool, emit_inbox: bool,
                        extracts: bool, routes):
+    # a: planes a point (1, or a lex point's 1 + value planes)
     has_buffer = k > 0
     refs = list(refs)
-    buf_ref = refs.pop(0) if has_buffer else None
-    act_ref = refs.pop(0)
-    xo_ref = refs.pop(0)
-    bo_ref = refs.pop(0) if has_buffer else None
-    ib_ref = refs.pop(0) if emit_inbox else None
+
+    def take(n):
+        out = refs[:n]
+        del refs[:n]
+        return out
+
+    d_refs, x_refs = take(a), take(a)
+    buf_refs = take(a) if has_buffer else None
+    (act_ref,) = take(1)
+    xo_refs = take(a)
+    bo_refs = take(a) if has_buffer else None
+    ib_refs = take(a) if emit_inbox else None
     nc_ref, ss_ref, cnt_ref, dsz_ref = refs
 
-    op = jnp.maximum if kind == "max" else jnp.bitwise_or
-    zero = jnp.zeros((), x_ref.dtype)
+    def join(u, v):
+        return _join(u, v, kind)
 
     # (1) local update: δ joins into x and the self slot  [Alg 2, lines 6-8]
-    x = x_ref[...]                                         # [g, N, bn]
-    d0 = d_ref[...]
+    x = tuple(r[...] for r in x_refs)                      # [g, N, bn] each
+    d0 = tuple(r[...] for r in d_refs)
     nc_ref[0, 0, :, :, 0] = _count_rows(d0, kind)          # |⇓δ| per node
-    x = op(x, d0)
+    x = join(x, d0)
     if has_buffer:
-        slots = [buf_ref[i] for i in range(k)]
-        slots[k - 1 if per_origin else 0] = \
-            op(slots[k - 1 if per_origin else 0], d0)
+        slots = [tuple(r[i] for r in buf_refs) for i in range(k)]
+        own = k - 1 if per_origin else 0
+        slots[own] = join(slots[own], d0)
 
     # (2) sends                                           [Alg 2, lines 9-12]
     if not has_buffer:                                     # state-based
         sends = [x] * p
     elif per_origin:                                       # bp/bprr: loo fold
-        zt = jnp.zeros_like(x)
+        zt = tuple(jnp.zeros_like(c) for c in x)
         prefix, suffix = [zt] * k, [zt] * k
         acc = zt
         for i in range(k):
             prefix[i] = acc
-            acc = op(acc, slots[i])
+            acc = join(acc, slots[i])
         acc = zt
         for i in range(k - 1, -1, -1):
             suffix[i] = acc
-            acc = op(acc, slots[i])
-        sends = [op(prefix[j], suffix[j]) for j in range(p)]
+            acc = join(acc, slots[i])
+        sends = [join(prefix[j], suffix[j]) for j in range(p)]
     else:                                                  # classic/rr: bcast
         sends = [slots[0]] * p
     for j in range(p):
@@ -108,43 +191,39 @@ def _round_step_kernel(d_ref, x_ref, *refs, g: int, np_: int, p: int, k: int,
     act = act_ref[...]                          # [g, N, P (+1: delivered)]
 
     def column(c):
-        return jnp.broadcast_to(act[:, :, c:c + 1], x.shape)
+        return jnp.broadcast_to(act[:, :, c:c + 1], x[0].shape)
 
     # (3) ack-gated buffer clear                          [Alg 2, line 13]
     if has_buffer:
         retain = column(p) == 0
-        slots = [jnp.where(retain, s, zero) for s in slots]
+        slots = [_where(retain, sl) for sl in slots]
 
     # (4) route + receive all P slots in order            [Alg 2, lines 14-17]
     for q in range(p):
         # Static routing: inbox[n] = sends[rev[n,q]] of node nbrs[n,q].
         # Topology padding slots route to (0, 0), masked off below.
-        dq = jnp.stack(
-            [sends[routes[q][n][0]][:, routes[q][n][1], :]
-             for n in range(np_)], axis=1)                 # [g, N, bn]
-        d = jnp.where(column(q) != 0, dq, zero)
-        if kind == "max":
-            novel = d > x
-            s = jnp.where(novel, d, zero)
-            cnt = jnp.sum(novel, axis=-1, dtype=jnp.int32)
-            x = jnp.maximum(x, d)
-        else:
-            s = jnp.bitwise_and(d, jnp.bitwise_not(x))
-            cnt = _count_rows(s, kind)
-            x = jnp.bitwise_or(x, d)
+        dq = tuple(
+            jnp.stack([sends[routes[q][n][0]][c][:, routes[q][n][1], :]
+                       for n in range(np_)], axis=1)       # [g, N, bn]
+            for c in range(a))
+        d = _where(column(q) != 0, dq)
+        s, cnt, x = _receive(d, x, kind)
         cnt_ref[0, 0, :, :, q] = cnt
         dsz_ref[0, 0, :, :, q] = _count_rows(d, kind)
         if emit_inbox:                  # classic/bp keep-gate is global; also
-            ib_ref[q] = d               # provenance replay (want_inbox)
+            for r, c in zip(ib_refs, d):  # provenance replay (want_inbox)
+                r[q] = c
         if extracts:                    # rr/bprr: Δ is ⊥ where not novel
-            slots[q if per_origin else 0] = op(slots[q if per_origin else 0],
-                                               s)
+            tgt = q if per_origin else 0
+            slots[tgt] = join(slots[tgt], s)
 
-    xo_ref[...] = x
+    for r, c in zip(xo_refs, x):
+        r[...] = c
     nc_ref[0, 0, :, :, 1] = _count_rows(x, kind)           # |⇓x'| per node
     if has_buffer:
         for i in range(k):
-            bo_ref[i] = slots[i]
+            for r, c in zip(bo_refs, slots[i]):
+                r[i] = c
 
 
 @functools.partial(
@@ -167,6 +246,11 @@ def round_step_2d(delta, x, buf, active, delivered, *, routes,
     sender_node) realizing inbox[n, q] = d_all[nbrs[n,q], rev[n,q]].
     ``block`` = (g, bn).
 
+    ``kind="lex"`` takes every state operand and result (``delta``, ``x``,
+    ``buf``, x', buf', inbox) as a tuple (t, v₁, …) of such arrays, joined
+    lexicographically (a record's value planes ride with its timestamp);
+    its launch is named ``round_step_lex``, the others ``round_step``.
+
     ``extracts`` merges the slot-order Δ extractions into the buffer
     in-kernel (rr/bprr). Historically it was the complement of
     ``emit_inbox``; it is independent now so provenance can request the
@@ -182,14 +266,18 @@ def round_step_2d(delta, x, buf, active, delivered, *, routes,
     """
     interpret = interpret_default() if interpret is None else interpret
     p = len(routes)
-    b, np_, u = x.shape
-    assert delta.shape == x.shape and delta.dtype == x.dtype
+    dts, xs = planes(delta, kind), planes(x, kind)
+    a = len(xs)
+    b, np_, u = xs[0].shape
+    assert all(c.shape == xs[0].shape for c in dts + xs)
+    assert [c.dtype for c in dts] == [c.dtype for c in xs]
     g, bn = block
     assert b % g == 0 and u % bn == 0
     grid = (b // g, u // bn)
     gb, gj = grid
     has_buffer = buf is not None
-    k = buf.shape[0] if has_buffer else 0
+    bufs = planes(buf, kind) if has_buffer else ()
+    k = bufs[0].shape[0] if has_buffer else 0
     if extracts is None:
         extracts = has_buffer and not emit_inbox
     assert not (extracts and not has_buffer)
@@ -200,12 +288,12 @@ def round_step_2d(delta, x, buf, active, delivered, *, routes,
     nc_shape = jax.ShapeDtypeStruct((gb, gj, g, np_, 2), jnp.int32)
     sl_shape = jax.ShapeDtypeStruct((gb, gj, g, np_, p), jnp.int32)
 
-    in_specs = [d_spec, d_spec]
-    args = [delta, x]
+    in_specs = [d_spec] * (2 * a)
+    args = list(dts + xs)
     if has_buffer:
         b_spec = pl.BlockSpec((k, g, np_, bn), lambda i, j: (0, i, 0, j))
-        in_specs.append(b_spec)
-        args.append(buf)
+        in_specs += [b_spec] * a
+        args += bufs
     act = active.astype(jnp.int32)
     if has_buffer:
         act = jnp.concatenate(
@@ -214,25 +302,37 @@ def round_step_2d(delta, x, buf, active, delivered, *, routes,
                                  lambda i, j: (i, 0, 0)))
     args.append(act)
 
-    out_specs = [d_spec]
-    out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype)]
+    out_specs = [d_spec] * a
+    out_shape = [jax.ShapeDtypeStruct(c.shape, c.dtype) for c in xs]
     if has_buffer:
-        out_specs.append(b_spec)
-        out_shape.append(jax.ShapeDtypeStruct(buf.shape, buf.dtype))
+        out_specs += [b_spec] * a
+        out_shape += [jax.ShapeDtypeStruct(c.shape, c.dtype) for c in bufs]
     if emit_inbox:
         ib_spec = pl.BlockSpec((p, g, np_, bn), lambda i, j: (0, i, 0, j))
-        out_specs.append(ib_spec)
-        out_shape.append(jax.ShapeDtypeStruct((p,) + x.shape, x.dtype))
+        out_specs += [ib_spec] * a
+        out_shape += [jax.ShapeDtypeStruct((p,) + c.shape, c.dtype)
+                      for c in xs]
     out_specs += [nc_spec, sl_spec, sl_spec, sl_spec]
     out_shape += [nc_shape, sl_shape, sl_shape, sl_shape]
+
+    # Wide lex points outgrow the default scoped VMEM: ask for room for the
+    # blocks and as much again for the values the round holds (sends, the
+    # leave-one-out prefixes and suffixes).
+    need = tile_bytes(a, k, p, g, np_, bn, emit_inbox)
+    extra = {}
+    if need > VMEM_DEFAULT // 2:
+        extra["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=min(VMEM_MAX, 3 * need))
 
     # x' and buf' overwrite x and buf in place: each grid step reads its
     # blocks before writing the same blocks back, and a store's buffer
     # stack is too large to hold twice in HBM.
-    aliases = {1: 0, 2: 1} if has_buffer else {1: 0}
+    aliases = {a + i: i for i in range(a)}
+    if has_buffer:
+        aliases.update({2 * a + i: a + i for i in range(a)})
     outs = pallas_call(
         functools.partial(_round_step_kernel, g=g, np_=np_, p=p, k=k,
-                          kind=kind, per_origin=per_origin,
+                          kind=kind, a=a, per_origin=per_origin,
                           emit_inbox=emit_inbox, extracts=extracts,
                           routes=routes),
         grid=grid,
@@ -241,12 +341,19 @@ def round_step_2d(delta, x, buf, active, delivered, *, routes,
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
-        name="round_step",
+        name="round_step_lex" if kind == "lex" else "round_step",
+        **extra,
     )(*args)
 
     outs = list(outs)
-    xo = outs.pop(0)
-    bo = outs.pop(0) if has_buffer else None
-    ib = outs.pop(0) if emit_inbox else None
+
+    def take():
+        out = outs[:a]
+        del outs[:a]
+        return point(out, kind)
+
+    xo = take()
+    bo = take() if has_buffer else None
+    ib = take() if emit_inbox else None
     nodecnt, ssend, cnt, dsz = outs
     return xo, bo, ib, nodecnt, ssend, cnt, dsz

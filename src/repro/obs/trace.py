@@ -75,14 +75,16 @@ class TraceLog:
         (a span opened with none open starts a new call), ``parent``, the
         name of the enclosing span (None at the root), and ``args``. The
         body gets that dict and may add the counts taken at the span's
-        end."""
+        end. The annotation carries ``call`` and the scalar ``args``."""
         parent, call = self._open[-1] if self._open \
             else (None, next(self._calls))
+        meta = {k: v for k, v in args.items()
+                if isinstance(v, (str, int, float))}
         args = {"call": call, "parent": parent, **args}
         self._open.append((name, call))
         t0 = self._now_us()
         try:
-            with jax.profiler.TraceAnnotation(name, call=call):
+            with jax.profiler.TraceAnnotation(name, call=call, **meta):
                 yield args
         finally:
             self._open.pop()

@@ -131,7 +131,8 @@ class SyncAlgorithm:
     @property
     def resolved_engine(self) -> str:
         """Requested engine after the dense-kernel fallback."""
-        return engine_mod.resolve(self.engine, self.lattice)
+        return engine_mod.resolve(self.engine, self.lattice,
+                                  resync=self.is_resync)
 
     @property
     def is_resync(self) -> bool:

@@ -16,9 +16,12 @@ DESIGN.md §11/§17. Three engines execute one synchronous round:
   digest_driven) take the fused per-phase kernels under ``mega``.
 
 Dispatch is by ``Lattice.kernel_kind``: lattices whose join/Δ have a dense
-single-array kernel ("max", "bitor") can run fused/mega; everything else
-(lex pairs, products, linear sums) silently falls back to the reference
-engine, so ``engine="fused"``/``"mega"`` is always safe to request.
+single-array kernel ("max", "bitor") can run fused/mega; lex maps ("lex":
+LWW maps, lex counters) run ``mega``'s delta family, whose kernel holds
+every plane of a (t, v₁, …) point; everything else (products, linear sums,
+and lex maps on ``fused`` or in the resync modes) falls back to the reference
+engine, so ``engine="fused"``/``"mega"`` is always safe to request —
+``resolve`` says which engine runs.
 
 All engines are bit-identical in final states, buffers, and metrics: max/or
 folds are exact and the kernels preserve Algorithm 2's slot-order
@@ -39,23 +42,29 @@ ENGINES = ("reference", "fused", "mega")
 # Engines that dispatch to the Pallas kernels (vs the pure-jnp reference).
 KERNEL_ENGINES = ("fused", "mega")
 
-# Kernel kinds the fused/mega engines implement end-to-end.
+# Kernel kinds the fused per-phase kernels implement end-to-end.
 FUSED_KINDS = ("max", "bitor")
+# ... and the megakernel's round (the delta family: state/classic/bp/rr/bprr).
+MEGA_KINDS = FUSED_KINDS + ("lex",)
 
 
 def supports_fused(lattice) -> bool:
-    """A lattice runs fused/mega iff its state is one dense array with a
-    kernel kind — exactly when ``kernel_kind`` is set (MapLattice only sets
-    it for arity-1 value lattices)."""
+    """A lattice runs the fused per-phase kernels iff its state is one
+    dense array with a kernel kind."""
     return getattr(lattice, "kernel_kind", None) in FUSED_KINDS
 
 
-def resolve(engine: str, lattice) -> str:
-    """Validate ``engine`` and apply the automatic jnp fallback."""
+def resolve(engine: str, lattice, *, resync: bool) -> str:
+    """Validate ``engine`` and apply the automatic jnp fallback: the engine
+    that runs. ``resync``: whether the algorithm is an anti-entropy mode,
+    which takes the fused per-phase kernels under ``mega`` too (DESIGN.md
+    §14), so a lex lattice runs ``reference`` there."""
     if engine not in ENGINES:
         raise ValueError(
             f"unknown engine {engine!r}; expected one of {ENGINES}")
-    if engine in KERNEL_ENGINES and not supports_fused(lattice):
+    kind = getattr(lattice, "kernel_kind", None)
+    kinds = MEGA_KINDS if engine == "mega" and not resync else FUSED_KINDS
+    if engine in KERNEL_ENGINES and kind not in kinds:
         return "reference"
     return engine
 
@@ -77,12 +86,12 @@ def gather_inbox(d_all, topo, batched: bool = False):
     return d_all[topo.nbrs, topo.rev]                    # [N, P, U]
 
 
-def _fold_slots(stack, kind: str):
-    """⊔ over the leading slot axis (P is small and static)."""
-    op = jnp.bitwise_or if kind == "bitor" else jnp.maximum
-    acc = stack[0]
-    for q in range(1, stack.shape[0]):
-        acc = op(acc, stack[q])
+def _fold_slots(stack, join):
+    """⊔ (the lattice's ``join``) over the leading slot axis of a state
+    pytree (P is small and static)."""
+    acc = jax.tree.map(lambda a: a[0], stack)
+    for q in range(1, jax.tree.leaves(stack)[0].shape[0]):
+        acc = join(acc, jax.tree.map(lambda a: a[q], stack))
     return acc
 
 
@@ -160,13 +169,13 @@ def fused_receive(algo, x, buf, buf_elems, cpu, d_all, acc_dtype,
         # neighbor slots; after a fault-free clear this is the identity.
         buf = buf.at[nbr_slots].set(lat.join(buf[nbr_slots], slot_vals))
     else:                                                # classic / rr
-        add = _fold_slots(stored, kind) if algo.extracts \
+        add = _fold_slots(stored, lat.join) if algo.extracts \
             else _fold_slots(
                 jnp.moveaxis(
                     jnp.where(keep[..., None], inbox,
                               jnp.zeros((), inbox.dtype)),
                     sax, 0),
-                kind)
+                lat.join)
         buf = lat.join(buf, add)
 
     cpu = cpu + algo._msum(ssz, acc_dtype)
@@ -203,22 +212,28 @@ def mega_round(algo, x, buf, buf_elems, op_delta, acc_dtype, faults=None,
     sax = algo.slot_axis
     batched = algo.batched
     nprefix = 2 if batched else 1
-    ushape = x.shape[nprefix:]
+    # States are pytrees: one array, or a lex kind's (t, v) pair of planes
+    # that the kernel holds side by side.
+    tmap = jax.tree.map
+    ushape = jax.tree.leaves(x)[0].shape[nprefix:]
 
     def flat3(a):                  # [.., N, *U] -> canonical [B, N, u]
         a = a.reshape(a.shape[:nprefix] + (-1,))
         return a if batched else a[None]
 
-    xv = flat3(x)
-    dv = flat3(op_delta)
-    bdim = xv.shape[0]
+    def flat_buf(a):               # [(B,) N, K, *U] -> [K, B, N, u]
+        a = a.reshape(a.shape[:sax + 1] + (-1,))
+        a = jnp.moveaxis(a, sax, 0)
+        return a if batched else a[:, None]
+
+    xv = tmap(flat3, x)
+    dv = tmap(flat3, op_delta)
+    bdim = jax.tree.leaves(xv)[0].shape[0]
     if algo.has_buffer:
-        if algo.per_origin:        # [(B,) N, K, *U] -> [K, B, N, u]
-            bv = buf.reshape(buf.shape[:sax + 1] + (-1,))
-            bv = jnp.moveaxis(bv, sax, 0)
-            bv = bv if batched else bv[:, None]
+        if algo.per_origin:
+            bv = tmap(flat_buf, buf)
         else:                      # flat buffer: K = 1
-            bv = flat3(buf)[None]
+            bv = tmap(lambda a: flat3(a)[None], buf)
     else:
         bv = None
 
@@ -243,8 +258,10 @@ def mega_round(algo, x, buf, buf_elems, op_delta, acc_dtype, faults=None,
         want_inbox=want_inbox, layout=algo.batch_layout)
 
     def engine_inbox(ib):          # [P, B, N, u] -> [(B,) N, P, ...U]
-        ib = jnp.moveaxis(ib if batched else ib[:, 0], 0, sax)
-        return ib.reshape(x.shape[:nprefix] + (p,) + ushape)
+        def one(a, xl):
+            a = jnp.moveaxis(a if batched else a[:, 0], 0, sax)
+            return a.reshape(xl.shape[:nprefix] + (p,) + ushape)
+        return tmap(one, ib, x)
 
     mib = engine_inbox(inbox) if want_inbox else None
 
@@ -277,7 +294,7 @@ def mega_round(algo, x, buf, buf_elems, op_delta, acc_dtype, faults=None,
         # (4) receive
         cpu = cpu + algo._msum(dsz, acc_dtype)
 
-    x = unb(xo).reshape(x.shape)
+    x = tmap(lambda o, xl: unb(o).reshape(xl.shape), xo, x)
     if algo.has_buffer:
         if algo.extracts:                        # rr / bprr: merged in-kernel
             ssz = cnt
@@ -285,21 +302,26 @@ def mega_round(algo, x, buf, buf_elems, op_delta, acc_dtype, faults=None,
             keep = cnt > 0                       # ¬(d ⊑ x_running)
             ssz = dsz * keep
         if algo.per_origin:
-            b_alg = jnp.moveaxis(bo if batched else bo[:, 0], 0, sax)
+            b_alg = tmap(lambda o, bl: jnp.moveaxis(
+                o if batched else o[:, 0], 0, sax).reshape(bl.shape),
+                bo, buf)
         else:
-            b_alg = bo[0] if batched else bo[0, 0]
-        b_alg = b_alg.reshape(buf.shape)
+            b_alg = tmap(lambda o, bl: (o[0] if batched else o[0, 0])
+                         .reshape(bl.shape), bo, buf)
         if not algo.extracts:
             ib = mib if mib is not None else engine_inbox(inbox)
             keep_u = keep.reshape(keep.shape + (1,) * len(ushape))
-            slot_vals = jnp.where(keep_u, ib, jnp.zeros((), ib.dtype))
+            slot_vals = tmap(
+                lambda a: jnp.where(keep_u, a, jnp.zeros((), a.dtype)), ib)
             if algo.per_origin:                  # bp
                 nbr_slots = (slice(None),) * sax + (slice(None, p),)
-                b_alg = b_alg.at[nbr_slots].set(
-                    lat.join(b_alg[nbr_slots], slot_vals))
+                joined = lat.join(tmap(lambda a: a[nbr_slots], b_alg),
+                                  slot_vals)
+                b_alg = tmap(lambda a, v: a.at[nbr_slots].set(v), b_alg,
+                             joined)
             else:                                # classic
-                b_alg = lat.join(
-                    b_alg, _fold_slots(jnp.moveaxis(slot_vals, sax, 0), kind))
+                stack = tmap(lambda a: jnp.moveaxis(a, sax, 0), slot_vals)
+                b_alg = lat.join(b_alg, _fold_slots(stack, lat.join))
         buf = b_alg
         cpu = cpu + algo._msum(ssz, acc_dtype)
         buf_elems = buf_elems + jnp.sum(ssz, axis=-1, dtype=jnp.int32)

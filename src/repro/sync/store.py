@@ -71,7 +71,9 @@ from repro.core.lattice import BatchWeights, Lattice
 from repro.obs import provenance as prv
 from repro.obs import telemetry as obs
 from repro.obs.trace import maybe_span
-from repro.sync.algorithms import RoundMetrics, SyncAlgorithm, metric_dtype
+from repro.sync import engine as engine_mod
+from repro.sync.algorithms import (RESYNC_ALGORITHMS, RoundMetrics,
+                                   SyncAlgorithm, metric_dtype)
 from repro.sync.digest import DigestSpec
 from repro.sync.faults import FaultSchedule, FaultViews
 from repro.sync.simulator import (
@@ -560,6 +562,7 @@ def simulate_store(
     shard partials without losing the per-element views).
     """
     with maybe_span(trace, "store_call", algo=algo, engine=engine,
+                    engine_ran=_engine_ran(algo, engine, lattice),
                     objects=spec.objects):
         return _simulate_store(
             algo, lattice, topo, spec, active_rounds, quiet_rounds, loo=loo,
@@ -626,6 +629,7 @@ def resume_store(
                 f"checkpoint step {step} under {ckpt.dir} records no "
                 f"chunk_rounds — pass chunk_rounds= explicitly")
     with maybe_span(trace, "store_call", algo=algo, engine=engine,
+                    engine_ran=_engine_ran(algo, engine, lattice),
                     objects=spec.objects, resume_round=step):
         return _simulate_store(
             algo, lattice, topo, spec, active_rounds, quiet_rounds, loo=loo,
@@ -635,6 +639,13 @@ def resume_store(
             object_metrics=object_metrics, pad_to=pad_to,
             telemetry=telemetry, provenance=provenance, trace=trace,
             resume=(ckpt, step, extra))
+
+
+def _engine_ran(algo: str, engine: str, lattice: Lattice) -> str:
+    """The engine a call runs after the automatic jnp fallback
+    (``engine.resolve``), recorded beside the one asked for."""
+    return engine_mod.resolve(engine, lattice,
+                              resync=algo in RESYNC_ALGORITHMS)
 
 
 def _simulate_store(algo, lattice, topo, spec, active_rounds, quiet_rounds,
@@ -694,6 +705,10 @@ def _simulate_store(algo, lattice, topo, spec, active_rounds, quiet_rounds,
     x0 = spec.x0
     if pad and x0 is not None:
         x0 = _pad_tree(x0, lattice.bottom(), pad, (n,))
+    elif x0 is not None and chunk_rounds is not None:
+        # The chunk program donates its input carry: the caller's x0 must
+        # outlive the call (a spec serves call after call).
+        x0 = jax.tree.map(jnp.copy, x0)
 
     total = active_rounds + quiet_rounds
     with maybe_span(trace, "store_build") as counts:

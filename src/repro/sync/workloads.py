@@ -34,6 +34,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -256,6 +257,86 @@ def versioned_slot_cell_op(counts: np.ndarray, obj: int,
         return jnp.where(sel, x + 1, 0)
 
     return op_fn
+
+
+@dataclasses.dataclass(frozen=True)
+class _LwwWrites:
+    """``lww_write_op``'s program over its four op tables."""
+
+    keys: int
+
+    def __call__(self, operands, x, t):
+        with jax.named_scope("lww_writes"):
+            upd, obj, key, val = operands
+            ts = x[0]                                  # [B, N, keys]
+            assert ts.shape[-1] == self.keys, (
+                f"op stream built for {self.keys} keys cannot serve "
+                f"objects of {ts.shape[-1]}")
+            assert len(x) == 1 + val.shape[-1], (
+                f"op stream of {val.shape[-1]} value planes cannot serve "
+                f"points of {len(x)} planes")
+            rounds, n, w = upd.shape
+            # int32 throughout: the simulator traces its scan under x64,
+            # where an unpinned index or clock would be emulated int64.
+            t = jnp.minimum(jnp.asarray(t, jnp.int32), rounds - 1)
+            node = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[:, None],
+                                    (n, w))
+            slot = jnp.arange(w, dtype=jnp.int32)[None, :]
+            stamp = 2 + (t * n + node) * w + slot      # unique per write
+            write = upd[t] != 0                        # [N, W]; reads: none
+            o, k = obj[t], key[t]
+            zero = jnp.zeros(ts.shape, jnp.int32)
+            dt = zero.at[o, node, k].max(jnp.where(write, stamp,
+                                                   jnp.int32(0)))
+            # The newest write of a (node, key) in the round carries its
+            # value planes: timestamps are unique, so exactly one write
+            # wins, and the others scatter out of bounds.
+            won = write & (dt[o, node, k] == stamp)
+            o = jnp.where(won, o, jnp.int32(ts.shape[0]))
+            return (dt,) + tuple(
+                zero.at[o, node, k].set(val[t][..., j], mode="drop")
+                for j in range(val.shape[-1]))
+
+
+def lww_write_op(tables, keys: int) -> OpStream:
+    """Store op stream of keyed last-writer-wins writes over LWW-map
+    objects (``LWWMap(keys, width)``: (t, v₁, …, v_width) points of
+    [B, N, keys] planes).
+
+    ``tables``: ``(is_update, object, key, value)``, the first three
+    [T, N, W] int32 and ``value`` [T, N, W, width] (or [T, N, W] for one
+    value plane): op slot w of node n in active round t writes the record
+    ``value`` under ``key`` of ``object`` where ``is_update`` is nonzero; a
+    read writes nothing. The write's timestamp is ``2 + (t·N + n)·W + w`` —
+    unique, ordered by round, then node, then slot (the stand-in for a
+    clock ordered by time, then node address), and above 1, the timestamp
+    of a loaded store's start state. A node's δ in a round is the lex join
+    of its writes: the newest write of each key.
+
+    The op_fn is an :class:`OpStream` over the four tables (device
+    arrays, operands of the store's compiled program); rounds past T read
+    the last round (the simulator gates quiet rounds' deltas to ⊥). Like
+    :func:`versioned_slot_op` it indexes the GLOBAL object axis, so it
+    does not serve ``simulate_store(shard=True)``.
+    """
+    if len(tables) != 4:
+        raise ValueError("lww_write_op needs four tables (is_update, "
+                         "object, key, value)")
+    shapes = {np.shape(a) for a in tables[:3]}
+    vshape = np.shape(tables[3])
+    if len(shapes) != 1 or len(next(iter(shapes))) != 3 or \
+            vshape[:3] != next(iter(shapes)) or len(vshape) not in (3, 4):
+        raise ValueError("lww_write_op needs three [T, N, W] tables "
+                         "(is_update, object, key) and a [T, N, W(, width)] "
+                         "value table")
+    rounds, n, w = next(iter(shapes))
+    if 2 + rounds * n * w > np.iinfo(np.int32).max:
+        raise ValueError(f"{rounds} x {n} x {w} writes overflow the int32 "
+                         f"timestamps")
+    value = np.asarray(tables[3])
+    ops = tuple(jnp.asarray(np.asarray(a), jnp.int32) for a in tables[:3]) \
+        + (jnp.asarray(value.reshape(value.shape[:3] + (-1,)), jnp.int32),)
+    return OpStream(_LwwWrites(keys), ops)
 
 
 def rotating_slot_op(nodes: int, slots: int) -> Callable:

@@ -7,18 +7,22 @@ For every algorithm in ALGORITHMS × every dense-kernel lattice kind
 (mesh, tree, random connected) × kernel engine (fused, mega), results must
 match the reference engine exactly: final states, per-round tx / mem /
 cpu / max-node-memory, and per-node buffer counts — fault-free and under
-composed fault schedules — and still converge. Lattices without a dense
-kernel (lex pairs) must silently fall back to the reference engine and
-behave identically.
+composed fault schedules — and still converge. Lex-pair maps (LWW maps)
+run the megakernel's lex kind under ``mega`` and fall back to the
+reference engine under ``fused`` and in the resync modes, bit-identically
+either way.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import BitGSet, GCounter, GSet, LWWMap
+from repro.core import LexCounter
 from repro.sync import (ALGORITHMS, FaultSchedule, SyncAlgorithm, converged,
                         engine, simulate, topology)
+from repro.sync.algorithms import RESYNC_ALGORITHMS
 
 N, T, Q = 9, 8, 10
 KERNEL_ENGINES = list(engine.KERNEL_ENGINES)
@@ -57,7 +61,7 @@ def bitgset_ops(n=N, rounds=T):
 
 
 def lww_ops(n=N):
-    """Lex-pair states: no dense kernel — exercises the silent fallback."""
+    """Lex-pair states: the megakernel's lex kind, the fused fallback."""
     lm = LWWMap(num_keys=n)
 
     def op_fn(x, t):
@@ -66,6 +70,31 @@ def lww_ops(n=N):
         dt = jnp.zeros_like(ts).at[idx, idx].set(t.astype(ts.dtype) + 1)
         dv = jnp.zeros_like(vals).at[idx, idx].set(idx.astype(vals.dtype) * 3)
         return (dt, dv)
+
+    return op_fn, lm.lattice
+
+
+def wide_lww_ops(n=N, width=3):
+    """LWW maps whose keys hold records of ``width`` value planes: node i
+    writes keys i and i + 1 at one timestamp, so its neighbour's write of
+    key i + 1 ties on the timestamp and the value planes decide."""
+    lm = LWWMap(num_keys=n, width=width)
+
+    def op_fn(x, t):
+        idx = jnp.arange(n)
+        out = []
+        for j, c in enumerate(x):
+            d = jnp.zeros_like(c)
+            if j == 0:
+                stamp = jnp.full((n,), t.astype(c.dtype) + 1)
+                vals = (stamp, stamp)
+            else:
+                vals = ((idx * 3 - j).astype(c.dtype),
+                        ((idx + 1) * 5 % 7 - j).astype(c.dtype))
+            d = d.at[idx, idx].set(vals[0])
+            d = d.at[idx, (idx + 1) % n].set(vals[1])
+            out.append(d)
+        return tuple(out)
 
     return op_fn, lm.lattice
 
@@ -130,9 +159,67 @@ def test_kernel_engines_bit_identical_faulted(algo, eng):
 @pytest.mark.parametrize("eng", KERNEL_ENGINES)
 @pytest.mark.parametrize("algo", ALGORITHMS)
 def test_lex_lattice_falls_back_and_matches(algo, eng):
+    """LWW maps run ``mega``'s lex kernel (the delta family) or fall back
+    to the reference engine (``fused``, the resync modes); either way the
+    final states, every per-round metric and, round by round, the states,
+    buffers and buffered-element counts are the reference engine's."""
     topo = topology.partial_mesh(N, 4)
     a, b, lat = _run_both(algo, WORKLOADS["lww"], topo, eng)
     _assert_identical(a, b, f"lww/{algo}/{eng}")
+    np.testing.assert_array_equal(a.uniform, b.uniform)
+    assert converged(lat, b.final_x)
+
+    op_fn, lat = lww_ops()
+    algs = {e: SyncAlgorithm(name=algo, lattice=lat, topo=topo, engine=e)
+            for e in ("reference", eng)}
+    runs = "mega" if eng == "mega" and algo not in RESYNC_ALGORITHMS \
+        else "reference"
+    assert algs[eng].resolved_engine == runs
+    carries = {e: al.init() for e, al in algs.items()}
+    for t in range(T):
+        delta = op_fn(carries["reference"].x, jnp.asarray(t))
+        for e, al in algs.items():
+            carries[e], _ = al.round_step(carries[e], delta)
+        want, got = carries["reference"], carries[eng]
+        for part in ("x", "buf", "buf_elems"):
+            for w, g in zip(jax.tree.leaves(getattr(want, part)),
+                            jax.tree.leaves(getattr(got, part))):
+                np.testing.assert_array_equal(
+                    np.asarray(w), np.asarray(g),
+                    err_msg=f"{algo}/{eng}: {part} @ round {t}")
+
+
+@pytest.mark.parametrize("algo", ALGORITHMS)
+def test_wide_lex_lattice_mega_matches(algo):
+    """LWW maps of three-plane records: ``mega`` runs the lex kernel for
+    the delta family (the resync modes fall back) and equals the reference
+    engine in final states and every per-round metric, with timestamp ties
+    decided by the value planes."""
+    topo = topology.partial_mesh(N, 4)
+    a, b, lat = _run_both(algo, wide_lww_ops, topo, "mega")
+    _assert_identical(a, b, f"wide-lww/{algo}")
+    assert len(b.final_x) == 4
+    np.testing.assert_array_equal(a.uniform, b.uniform)
+    assert converged(lat, b.final_x)
+    runs = "reference" if algo in RESYNC_ALGORITHMS else "mega"
+    assert SyncAlgorithm(name=algo, lattice=lat, topo=topo,
+                         engine="mega").resolved_engine == runs
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("algo", ["classic", "bprr"])
+def test_lex_mega_random_topologies(seed, algo):
+    """LWW maps on ``mega`` over random connected graphs with ragged
+    degrees match the reference engine."""
+    rng = np.random.default_rng(seed + 10)
+    n = int(rng.integers(5, 11))
+    adj = np.zeros((n, n), bool)
+    for i in range(1, n):
+        j = int(rng.integers(0, i))
+        adj[i, j] = adj[j, i] = True
+    topo = topology._from_adj(f"lexrand{seed}", adj)
+    a, b, lat = _run_both(algo, lambda: lww_ops(n), topo, "mega")
+    _assert_identical(a, b, f"lexrand{seed}/{algo}")
     assert converged(lat, b.final_x)
 
 
@@ -192,22 +279,35 @@ def test_engine_buffer_counts_identical():
 
 
 def test_engine_resolution():
-    assert engine.resolve("fused", GSet(universe=8).lattice) == "fused"
-    assert engine.resolve("fused", BitGSet(universe=64).lattice) == "fused"
-    assert engine.resolve("fused", LWWMap(num_keys=4).lattice) == "reference"
-    assert engine.resolve("mega", GSet(universe=8).lattice) == "mega"
-    assert engine.resolve("mega", BitGSet(universe=64).lattice) == "mega"
-    assert engine.resolve("mega", LWWMap(num_keys=4).lattice) == "reference"
-    assert engine.resolve("reference", GSet(universe=8).lattice) == "reference"
+    def run(eng, lat, resync=False):
+        return engine.resolve(eng, lat, resync=resync)
+
+    assert run("fused", GSet(universe=8).lattice) == "fused"
+    assert run("fused", BitGSet(universe=64).lattice) == "fused"
+    assert run("fused", LWWMap(num_keys=4).lattice) == "reference"
+    assert run("mega", GSet(universe=8).lattice) == "mega"
+    assert run("mega", BitGSet(universe=64).lattice) == "mega"
+    # the megakernel runs lex maps, narrow or wide; the fused kernels,
+    # which the resync modes take under mega too, do not
+    assert run("mega", LWWMap(num_keys=4).lattice) == "mega"
+    assert run("mega", LWWMap(num_keys=4, width=3).lattice) == "mega"
+    assert run("mega", LexCounter(3).lattice) == "mega"
+    assert run("mega", LWWMap(num_keys=4).lattice, resync=True) == "reference"
+    assert run("mega", GSet(universe=8).lattice, resync=True) == "mega"
+    assert run("reference", GSet(universe=8).lattice) == "reference"
     with pytest.raises(ValueError):
-        engine.resolve("warp", GSet(universe=8).lattice)
+        run("warp", GSet(universe=8).lattice)
+    with pytest.raises(TypeError):      # no default: the caller must say
+        engine.resolve("mega", LWWMap(num_keys=4).lattice)
 
 
 def test_kernel_kind_assignments():
     assert GSet(universe=8).lattice.kernel_kind == "max"
     assert GCounter(4).lattice.kernel_kind == "max"
     assert BitGSet(universe=64).lattice.kernel_kind == "bitor"
-    assert LWWMap(num_keys=4).lattice.kernel_kind is None
+    assert LWWMap(num_keys=4).lattice.kernel_kind == "lex"
+    assert LWWMap(num_keys=4, width=25).lattice.kernel_kind == "lex"
+    assert LexCounter(3).lattice.kernel_kind == "lex"
 
 
 @pytest.mark.parametrize("eng", KERNEL_ENGINES)

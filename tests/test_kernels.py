@@ -44,19 +44,6 @@ def test_delta_extract_sweep(shape, kind, rng):
     assert int(cnt) == int(rcnt)
 
 
-@pytest.mark.parametrize("shape", [(100,), (7, 333), (512, 257)])
-def test_lex_join_delta_sweep(shape, rng):
-    ta, tb = (jnp.asarray(rng.integers(0, 5, size=shape), jnp.int32) for _ in range(2))
-    va, vb = (jnp.asarray(rng.integers(0, 5, size=shape), jnp.int32) for _ in range(2))
-    (t, v), (dt_, dv), cnt = ops.lex_join_delta((ta, va), (tb, vb))
-    rt, rv, rdt, rdv, rcnt = ref.lex_join_delta(ta, va, tb, vb)
-    np.testing.assert_array_equal(t, rt)
-    np.testing.assert_array_equal(v, rv)
-    np.testing.assert_array_equal(dt_, rdt)
-    np.testing.assert_array_equal(dv, rdv)
-    assert int(cnt) == int(rcnt)
-
-
 @pytest.mark.parametrize("k", [2, 3, 5, 9])
 @pytest.mark.parametrize("n", [100, 4096])
 def test_buffer_fold_sweep(k, n, rng):
@@ -108,10 +95,20 @@ def test_bitpacked_gset_roundtrip_and_join(universe, seed):
 
 # -- sync-round megakernel vs whole-round oracle (DESIGN.md §17) --------------
 
-def _mega_case(rng, b, n, u, p, k, kind, per_origin, extracts, topo):
+def _mega_case(rng, b, n, u, p, k, kind, per_origin, extracts, topo,
+               block=None, width=1):
     dtype = jnp.uint32 if kind == "bitor" else jnp.int32
-    hi = 2**31 if kind == "bitor" else 50
-    mk = lambda *s: jnp.asarray(rng.integers(0, hi, size=s), dtype)
+    hi = {"bitor": 2**31, "max": 50, "lex": 4}[kind]
+
+    def mk(*s):
+        one = lambda lo=0: jnp.asarray(rng.integers(lo, hi, size=s), dtype)
+        # lex: few timestamps and values, so equal ones with other values
+        # (ties decided by a later plane) and ⊥ points are common; wide
+        # records' value planes hold negative words too
+        lo = -2 if width > 1 else 0
+        return ((one(),) + tuple(one(lo) for _ in range(width))
+                if kind == "lex" else one())
+
     delta, x = mk(b, n, u), mk(b, n, u)
     buf = mk(k, b, n, u) if k else None
     active = jnp.asarray(
@@ -120,14 +117,18 @@ def _mega_case(rng, b, n, u, p, k, kind, per_origin, extracts, topo):
         if k else None
     kw = dict(nbrs=topo.nbrs, rev=topo.rev, kind=kind,
               per_origin=per_origin, extracts=extracts)
-    got = ops.sync_round(delta, x, buf, active, delivered, **kw)
+    got = ops.sync_round(delta, x, buf, active, delivered, block=block, **kw)
     want = ref.sync_round(delta, x, buf, active, delivered, **kw)
     names = ("x'", "buf'", "inbox", "dsz_op", "xsz", "ssend", "cnt", "dsz")
     for nm, g, w in zip(names, got, want):
         if w is None:
             assert g is None, nm
-        else:
-            np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+            continue
+        gl, wl = jax.tree.leaves(g), jax.tree.leaves(w)
+        assert len(gl) == len(wl) == (1 + width if kind == "lex" and nm in
+                                      names[:3] else 1), nm
+        for a, c in zip(gl, wl):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(c),
                                           err_msg=nm)
 
 
@@ -142,7 +143,7 @@ MEGA_FLAVORS = {
 }
 
 
-@pytest.mark.parametrize("kind", ["max", "bitor"])
+@pytest.mark.parametrize("kind", ["max", "bitor", "lex"])
 @pytest.mark.parametrize("flavor", sorted(MEGA_FLAVORS))
 @pytest.mark.parametrize("b", [1, 3])
 def test_sync_round_megakernel_vs_oracle(kind, flavor, b, rng):
@@ -154,6 +155,81 @@ def test_sync_round_megakernel_vs_oracle(kind, flavor, b, rng):
     k = p + 1 if k == "P+1" else k
     _mega_case(rng, b, topo.num_nodes, 333, p, k, kind, per_origin,
                extracts, topo)
+
+
+@pytest.mark.parametrize("flavor", sorted(MEGA_FLAVORS))
+@pytest.mark.parametrize("seed", [0, 1])
+def test_sync_round_lex_ragged_topology(flavor, seed):
+    """The lex kind over a random connected graph with ragged degrees: the
+    padding slots route to (0, 0) and are masked off in VMEM."""
+    from repro.sync import topology
+
+    rng = np.random.default_rng(seed)
+    n = int(rng.integers(5, 10))
+    adj = np.zeros((n, n), bool)
+    for i in range(1, n):
+        j = int(rng.integers(0, i))
+        adj[i, j] = adj[j, i] = True
+    topo = topology._from_adj(f"ragged{seed}", adj)
+    p = topo.max_degree
+    k, per_origin, extracts = MEGA_FLAVORS[flavor]
+    k = p + 1 if k == "P+1" else k
+    _mega_case(rng, 2, n, 200, p, k, "lex", per_origin, extracts, topo)
+
+
+@pytest.mark.parametrize("flavor", ["classic", "bprr"])
+def test_sync_round_lex_lane_tiles(flavor, rng):
+    """The lex kind over several lane tiles and row groups, the last lane
+    tile padded (300 keys in tiles of 128): counts summed over the tiles
+    and the states are the oracle's."""
+    from repro.sync import topology
+
+    topo = topology.partial_mesh(6, 4)
+    p = topo.max_degree
+    k, per_origin, extracts = MEGA_FLAVORS[flavor]
+    k = p + 1 if k == "P+1" else k
+    _mega_case(rng, 4, 6, 300, p, k, "lex", per_origin, extracts, topo,
+               block=(2, 128))
+
+
+@pytest.mark.parametrize("flavor", sorted(MEGA_FLAVORS))
+@pytest.mark.parametrize("b", [1, 3])
+def test_sync_round_lex_wide_records(flavor, b, rng):
+    """Records of three value planes: one lex mask selects every plane of
+    the larger point, ties of the timestamp go to the value planes in
+    order, and a point counts where any plane is nonzero."""
+    from repro.sync import topology
+
+    topo = topology.partial_mesh(7, 4)
+    p = topo.max_degree
+    k, per_origin, extracts = MEGA_FLAVORS[flavor]
+    k = p + 1 if k == "P+1" else k
+    _mega_case(rng, b, 7, 200, p, k, "lex", per_origin, extracts, topo,
+               width=3)
+
+
+@pytest.mark.parametrize("shape", [(1, 5, 100), (1, 7, 333), (64, 8, 257)])
+def test_sync_round_lex_join_delta(shape, rng):
+    """The lex kind's join and Δ: the oracle's lex join and Δ are the
+    LWW map lattice's own, for pairs and for records of three value
+    planes, and the kernel's rr round (Δ-extractions joined into the
+    buffer, each slot joined into the state) is the oracle's."""
+    from repro.core import LWWMap
+    from repro.sync import topology
+
+    b, n, u = shape
+    for width in (1, 3):
+        lat = LWWMap(num_keys=u, width=width).lattice
+        pt = lambda: tuple(jnp.asarray(rng.integers(0, 5, size=shape),
+                                       jnp.int32) for _ in range(1 + width))
+        a, c = pt(), pt()
+        for got, want in ((ref.join(a, c, "lex"), lat.join(a, c)),
+                          (ref.lex_delta(c, a)[0], lat.delta(c, a))):
+            assert len(got) == len(want) == 1 + width
+            for g, w in zip(got, want):
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    topo = topology.ring(n)
+    _mega_case(rng, b, n, u, topo.max_degree, 1, "lex", False, True, topo)
 
 
 @pytest.mark.parametrize("layout_block", [(1, 128), (2, 128), (4, 256)])

@@ -826,3 +826,173 @@ def test_program_cache_evicts_least_recently_used():
     assert cache.get(None, build("x")) == ("x", "miss")   # never kept
     assert built == ["a", "b", "c", "b", "x"]
     assert cache.info() == (1, 5, 2, 2)
+
+
+# -- LWW maps: the megakernel's lex kind and the LWW write stream ------------
+
+LB, LN, LK, LW, LACTIVE, LQUIET = 3, 6, 40, 12, 4, 5
+
+
+def _lww_tables(seed, width=1):
+    """[T, N, W] (is_update, object, key) tables and the value table
+    ([T, N, W] for one value plane, else [T, N, W, width], of any int32);
+    few keys, so a node often writes one key twice in a round."""
+    rng = np.random.default_rng(seed)
+    shape = (LACTIVE, LN, LW)
+    vshape = shape if width == 1 else shape + (width,)
+    return (rng.integers(0, 2, shape), rng.integers(0, LB, shape),
+            rng.integers(0, 6, shape),
+            rng.integers(-2**31, 2**31 - 1, vshape) if width > 1
+            else rng.integers(0, 2**31 - 1, shape))
+
+
+def _lww_spec(seed=0, x0=True, width=1):
+    from repro.core import LWWMap
+
+    lat = LWWMap(LK, width).lattice
+    start = None
+    if x0:
+        vals = np.random.default_rng(seed + 100).integers(
+            0, 50, (width, LB, 1, LK))
+        start = (jnp.ones((LB, LN, LK), jnp.int32),) + tuple(
+            jnp.asarray(np.broadcast_to(v, (LB, LN, LK)), jnp.int32)
+            for v in vals)
+    return lat, StoreSpec(objects=LB,
+                          op_fn=W.lww_write_op(_lww_tables(seed, width), LK),
+                          weights=np.full(LB, 100.0), x0=start)
+
+
+def test_lww_write_op_newest_write_wins():
+    """A node's delta in a round holds, per written key, the timestamp
+    ``2 + (t·N + n)·W + w`` of its newest write and that write's value;
+    reads and unwritten keys are ⊥."""
+    tables = _lww_tables(3)
+    op = W.lww_write_op(tables, LK)
+    x = (jnp.zeros((LB, LN, LK), jnp.int32),) * 2
+    for t in range(LACTIVE + 1):
+        dt, dv = op(x, jnp.int32(t))
+        tt = min(t, LACTIVE - 1)
+        want_t = np.zeros((LB, LN, LK), np.int64)
+        want_v = np.zeros((LB, LN, LK), np.int64)
+        for n in range(LN):
+            for w in range(LW):
+                if tables[0][tt, n, w]:
+                    o, k = tables[1][tt, n, w], tables[2][tt, n, w]
+                    want_t[o, n, k] = 2 + (tt * LN + n) * LW + w
+                    want_v[o, n, k] = tables[3][tt, n, w]
+        np.testing.assert_array_equal(np.asarray(dt), want_t)
+        np.testing.assert_array_equal(np.asarray(dv), want_v)
+        assert dt.dtype == dv.dtype == jnp.int32
+
+
+def test_lww_write_op_wide_records():
+    """A record of several value planes: the newest write of a (node, key)
+    carries every plane of its record, negative words included."""
+    width = 3
+    tables = _lww_tables(4, width)
+    op = W.lww_write_op(tables, LK)
+    x = (jnp.zeros((LB, LN, LK), jnp.int32),) * (1 + width)
+    for t in range(LACTIVE):
+        got = op(x, jnp.int32(t))
+        assert len(got) == 1 + width
+        want = np.zeros((1 + width, LB, LN, LK), np.int64)
+        for n in range(LN):
+            for w in range(LW):
+                if tables[0][t, n, w]:
+                    o, k = tables[1][t, n, w], tables[2][t, n, w]
+                    want[0, o, n, k] = 2 + (t * LN + n) * LW + w
+                    want[1:, o, n, k] = tables[3][t, n, w]
+        for g, wnt in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), wnt)
+    with pytest.raises(AssertionError, match="value planes"):
+        op((jnp.zeros((LB, LN, LK), jnp.int32),) * 2, jnp.int32(0))
+
+
+def test_lww_write_op_validation():
+    t = _lww_tables(0)
+    with pytest.raises(ValueError, match="four"):
+        W.lww_write_op(t[:3], LK)
+    with pytest.raises(ValueError, match="overflow"):
+        # shapes are checked before any table is copied: a broadcast
+        # table holds no memory
+        W.lww_write_op((np.broadcast_to(np.int32(0), (2**12, 2**10, 2**10)),)
+                       * 4, LK)
+    with pytest.raises(ValueError, match="value table"):
+        W.lww_write_op(t[:3] + (t[3][:, :, :2],), LK)
+
+
+@pytest.mark.parametrize("algo", ["state", "classic", "bp", "rr", "bprr"])
+def test_lww_store_mega_bit_identical(algo):
+    """An LWW-map store from a loaded start state runs the megakernel's
+    lex kind under ``mega``: final states and every per-object per-round
+    metric equal the reference engine's, chunked or not."""
+    lat, spec = _lww_spec()
+    topo = topology.partial_mesh(LN, 4)
+    want = simulate_store(algo, lat, topo, spec, LACTIVE, LQUIET,
+                          engine="reference", track_convergence=True)
+    got = simulate_store(algo, lat, topo, spec, LACTIVE, LQUIET,
+                         engine="mega", chunk_rounds=3,
+                         track_convergence=True)
+    for a, b in zip(want.final_x, got.final_x):
+        np.testing.assert_array_equal(a, b)
+    for f in ("tx", "mem", "cpu", "max_mem_node", "uniform", "tx_bytes"):
+        np.testing.assert_array_equal(getattr(want, f), getattr(got, f),
+                                      err_msg=f)
+    assert want.tx.sum() > 0 and got.uniform[:, -1].all()
+
+
+@pytest.mark.parametrize("algo", ["state", "classic", "bp", "rr", "bprr"])
+def test_wide_lww_store_mega_bit_identical(algo):
+    """LWW maps whose keys hold records of three value planes run the lex
+    kernel under ``mega`` bit-identically to the reference engine."""
+    lat, spec = _lww_spec(seed=5, width=3)
+    topo = topology.partial_mesh(LN, 4)
+    want = simulate_store(algo, lat, topo, spec, LACTIVE, LQUIET,
+                          engine="reference", track_convergence=True)
+    got = simulate_store(algo, lat, topo, spec, LACTIVE, LQUIET,
+                         engine="mega", chunk_rounds=3,
+                         track_convergence=True)
+    assert len(got.final_x) == 4
+    for a, b in zip(want.final_x, got.final_x):
+        np.testing.assert_array_equal(a, b)
+    for f in ("tx", "mem", "cpu", "max_mem_node", "uniform", "tx_bytes"):
+        np.testing.assert_array_equal(getattr(want, f), getattr(got, f),
+                                      err_msg=f)
+    assert want.tx.sum() > 0 and got.uniform[:, -1].all()
+
+
+def test_chunked_store_keeps_the_callers_x0():
+    """The chunk program donates its input carry; a spec's x0 serves call
+    after call."""
+    lat, spec = _lww_spec()
+    topo = topology.partial_mesh(LN, 4)
+    runs = [simulate_store("bprr", lat, topo, spec, LACTIVE, LQUIET,
+                           engine="mega", chunk_rounds=2) for _ in range(2)]
+    assert not any(a.is_deleted() for a in spec.x0)
+    for a, b in zip(runs[0].final_x, runs[1].final_x):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_lww_write_op_traces_in_int32_under_the_metric_context():
+    """The simulator traces the op stream under x64: the LWW write stream
+    pins its indices, clock and planes to int32, so its jaxpr holds no
+    64-bit integer (emulated on the chip)."""
+    import jax
+
+    from repro.sync.simulator import metric_context
+
+    def dtypes(jx):
+        for eqn in jx.eqns:
+            for v in list(eqn.invars) + list(eqn.outvars):
+                if hasattr(v, "aval"):
+                    yield v.aval.dtype
+        for sub in jax.core.subjaxprs(jx):
+            yield from dtypes(sub)
+
+    for width in (1, 3):
+        op = W.lww_write_op(_lww_tables(1, width), LK)
+        x = (jnp.zeros((LB, LN, LK), jnp.int32),) * (1 + width)
+        with metric_context(True):
+            jaxpr = jax.make_jaxpr(op.apply)(op.operands, x, jnp.int32(2))
+        wide = {str(d) for d in dtypes(jaxpr.jaxpr)} & {"int64", "uint64"}
+        assert not wide, (width, wide)

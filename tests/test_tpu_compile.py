@@ -92,6 +92,33 @@ def test_round_step_compiles_at_store_width(one_chip, algo):
     _round(one_chip, algo, STORE_B, STORE_N, STORE_U, jnp.int32, "max")
 
 
+@pytest.mark.parametrize("algo", ["bprr", "classic"])
+def test_round_step_compiles_for_wide_lex_records(one_chip, algo):
+    """The lex kind at the YCSB cell's tile: 26 planes a point (a
+    timestamp and a 100 B field's 25 words) of 1,280 keys on a 50-node
+    mesh, a few objects (the tile, and so the VMEM it takes, does not
+    depend on their count)."""
+    topo = topology.partial_mesh(STORE_N, P)
+    b, u, planes = 4, 1_280, 26
+    k = P + 1 if algo == "bprr" else 1
+    blk, _ = kops.sync_round_block(b, STORE_N, u, p=P, k=k, kind="lex",
+                                   layout="rows", n_planes=planes)
+    assert blk == (1, 128)
+
+    def step(d, x, buf, act, dlv):
+        return kops.sync_round(d, x, buf, act, dlv, nbrs=topo.nbrs,
+                               rev=topo.rev, kind="lex",
+                               per_origin=algo == "bprr",
+                               extracts=algo == "bprr", layout="rows",
+                               interpret=False)
+
+    point = lambda *s: (_spec(one_chip, s, jnp.int32),) * planes
+    compile_for_chip(step, point(b, STORE_N, u), point(b, STORE_N, u),
+                     point(k, b, STORE_N, u),
+                     _spec(one_chip, (b, STORE_N, P), jnp.bool_),
+                     _spec(one_chip, (b, STORE_N), jnp.bool_))
+
+
 @pytest.mark.parametrize("dtype,kind", [(jnp.bool_, "max"),
                                         (jnp.uint32, "bitor")],
                          ids=["gset_bool", "bitgset_uint32"])

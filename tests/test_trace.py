@@ -233,6 +233,45 @@ def test_untraced_store_opens_no_annotation(monkeypatch):
     assert np.array_equal(plain.final_state_bytes, traced.final_state_bytes)
 
 
+@pytest.mark.parametrize("lattice, engine, ran", [
+    ("lww", "fused", "reference"), ("lww", "mega", "mega"),
+    ("slots", "fused", "fused")])
+def test_store_call_records_the_engine_that_ran(monkeypatch, lattice,
+                                                engine, ran):
+    """``store_call`` records the engine asked for and the one that ran
+    after the automatic fallback (LWW maps have no fused kernel), in the
+    log and on the profiler annotation."""
+    from repro.core import LWWMap
+
+    notes = []
+    real = jax.profiler.TraceAnnotation
+
+    def noting(name, **kw):
+        notes.append((name, kw))
+        return real(name, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", noting)
+    b, n, keys = 2, 5, 16
+    if lattice == "lww":
+        lat = LWWMap(keys).lattice
+        rng = np.random.default_rng(0)
+        tables = (rng.integers(0, 2, (2, n, 3)), rng.integers(0, b, (2, n, 3)),
+                  rng.integers(0, keys, (2, n, 3)),
+                  rng.integers(0, 99, (2, n, 3)))
+        spec = StoreSpec(objects=b, op_fn=workloads.lww_write_op(tables,
+                                                                 keys))
+        topo = topology.partial_mesh(n, 4)
+    else:
+        lat, topo, spec = _store()
+    log = TraceLog()
+    simulate_store("bprr", lat, topo, spec, 2, 2, engine=engine, trace=log)
+    (call,) = _spans(log, "store_call")
+    assert call["args"]["engine"] == engine
+    assert call["args"]["engine_ran"] == ran
+    (kw,) = [kw for name, kw in notes if name == "store_call"]
+    assert kw["engine"] == engine and kw["engine_ran"] == ran
+
+
 def test_store_spans_label_the_profiler_host_timeline(tmp_path):
     """Under ``jax.profiler`` every span of the log is also an event of
     the same name on the trace's host plane."""
